@@ -42,6 +42,7 @@ import numpy as np
 
 import repro.runtime.engine as engine_mod
 from repro.configs.base import get_reduced
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models.api import Model
 from repro.runtime.engine import InferenceEngine
 
@@ -185,6 +186,7 @@ def main() -> None:
     ap.add_argument("--archs", nargs="*", default=list(ARCHS))
     ap.add_argument("--block-size", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
     requests = 4 if args.quick else 8
     max_new = 16 if args.quick else 32
     n_slots, max_len = 2, 128

@@ -6,14 +6,15 @@ block is a single rolled loop — were previously only observable at
 runtime (HOST_SYNCS deltas, ``live_bytes`` checks). This pass proves
 them ahead of time from the compiled executable's HLO text:
 
-* **donation aliasing** — the ``u8[total_size]`` state parameter must
-  appear in the module's ``input_output_alias`` table; a silently
-  dropped donation doubles peak state memory and breaks the
-  planned-layout-is-live-layout contract (error);
+* **donation aliasing** — the state parameter (the one of the state
+  buffer's exact type, e.g. ``bf16[939524096]``) must appear in the
+  module's ``input_output_alias`` table; a silently dropped donation
+  doubles peak state memory and breaks the planned-layout-is-live-layout
+  contract (error);
 * **host transfers** — no outfeed/infeed/send/recv, no host memory
   space (``S(5)``) shapes, no host-placement custom-calls (error);
-* **state-buffer copies/converts** — plain ``copy``/``convert`` ops the
-  size of the whole state buffer. On the CPU backend the scan body is
+* **state-buffer copies/converts** — plain ``copy``/``convert`` ops of
+  the whole state buffer's type. On the CPU backend the scan body is
   known to emit a bounded number of full-buffer copies around its
   nested scatter loops even with donation intact, so these report as
   warnings with their location, not errors;
@@ -100,13 +101,32 @@ class DecodeProgram:
 
     label: str  # e.g. "qwen3-0.6b:step" / "qwen3-0.6b:block8"
     hlo: str  # compiled.as_text()
-    state_nbytes: int  # StatePlan.total_size — identifies the buffer
+    state_type: str  # HLO type of the state buffer, e.g. "f32[16384]"
     expect_trip: int | None = None  # scan length for block programs
+
+
+_HLO_DTYPES = {
+    "bool": "pred", "int8": "s8", "uint8": "u8", "int16": "s16",
+    "uint16": "u16", "int32": "s32", "uint32": "u32", "int64": "s64",
+    "uint64": "u64", "bfloat16": "bf16", "float16": "f16",
+    "float32": "f32", "float64": "f64",
+}
+
+
+def hlo_type(aval) -> str:
+    """``aval``'s HLO type without layout, e.g. ``bf16[8,128]``."""
+    import numpy as np
+
+    dims = ",".join(str(int(d)) for d in aval.shape)
+    return f"{_HLO_DTYPES[np.dtype(aval.dtype).name]}[{dims}]"
 
 
 def lint_program(prog: DecodeProgram) -> list[Finding]:
     """All static checks over one compiled decode program's HLO."""
-    from repro.launch.hlo_analysis import _type_bytes, parse_hlo
+    from repro.launch.hlo_analysis import parse_hlo
+
+    def is_state(type_str: str) -> bool:
+        return type_str.split("{", 1)[0] == prog.state_type
 
     findings: list[Finding] = []
     comps, entry = parse_hlo(prog.hlo)
@@ -118,15 +138,13 @@ def lint_program(prog: DecodeProgram) -> list[Finding]:
     state_params = [
         int(inst.raw_operands)
         for inst in comps[entry].instructions
-        if inst.opcode == "parameter"
-        and inst.result_type.startswith("u8")
-        and _type_bytes(inst.result_type) == prog.state_nbytes
+        if inst.opcode == "parameter" and is_state(inst.result_type)
     ]
     if not state_params:
         findings.append(
             _finding(
                 "state-param-missing",
-                f"no u8[{prog.state_nbytes}] parameter in the entry "
+                f"no {prog.state_type} parameter in the entry "
                 f"computation — the state buffer is not an input of the "
                 f"compiled program",
                 prog.label,
@@ -139,7 +157,7 @@ def lint_program(prog: DecodeProgram) -> list[Finding]:
                 _finding(
                     "state-not-donated",
                     f"state buffer (parameter {param}, "
-                    f"{prog.state_nbytes} B) absent from the "
+                    f"{prog.state_type}) absent from the "
                     f"input_output_alias table: donation did not alias, "
                     f"decode double-buffers the whole state",
                     prog.label,
@@ -190,7 +208,7 @@ def lint_program(prog: DecodeProgram) -> list[Finding]:
             if (
                 inst.opcode in ("copy", "convert")
                 and comp.name not in fusion_bodies
-                and _type_bytes(inst.result_type) == prog.state_nbytes
+                and is_state(inst.result_type)
             ):
                 copy_sites.append(f"{comp.name}{inst.name}[{inst.opcode}]")
     if copy_sites:
@@ -287,6 +305,7 @@ def lower_decode_programs(
         StateResidency,
         resident_block_impl,
         resident_decode_impl,
+        state_buffer_aval,
     )
     from repro.runtime.sampling import SamplingParams, TokenSampler
 
@@ -301,7 +320,7 @@ def lower_decode_programs(
     resid = StateResidency(sp, caches, n_slots=n_slots)
     params_aval = jax.eval_shape(model.init, jax.random.PRNGKey(0))
 
-    buf_aval = jax.ShapeDtypeStruct((sp.total_size,), jnp.uint8)
+    buf_aval = state_buffer_aval(sp)
     tok_aval = jax.ShapeDtypeStruct((n_slots, 1), jnp.int32)
     vec_i32 = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
     vec_bool = jax.ShapeDtypeStruct((n_slots,), jnp.bool_)
@@ -318,7 +337,7 @@ def lower_decode_programs(
             .lower(params_aval, tok_aval, buf_aval, vec_i32, vec_bool)
             .compile()
             .as_text(),
-            state_nbytes=sp.total_size,
+            state_type=hlo_type(buf_aval),
         )
     ]
 
@@ -337,7 +356,7 @@ def lower_decode_programs(
                        vec_bool, vec_i32, keys_aval, eos_aval)
                 .compile()
                 .as_text(),
-                state_nbytes=sp.total_size,
+                state_type=hlo_type(buf_aval),
                 expect_trip=block,
             )
         )
@@ -361,18 +380,13 @@ def lint_executables(bundle) -> list[Finding]:
     if pack is None:
         return []
     from repro.runtime.aot import deserialize_compiled
+    from repro.runtime.residency import state_buffer_aval
 
     findings: list[Finding] = []
     sp = bundle.state_plan
     # Paged buckets donate the *physical* pool buffer (null page + pool
-    # pages), not the logical symmetric region — lint against that size.
-    state_nbytes = 0
-    if sp is not None:
-        state_nbytes = (
-            sp.phys_total_size
-            if getattr(sp, "page_size", None) is not None
-            else sp.total_size
-        )
+    # pages), not the logical symmetric region — lint against that.
+    state_type = hlo_type(state_buffer_aval(sp)) if sp is not None else ""
     for name, entry in sorted(pack.entries.items()):
         label = f"{bundle.arch}:{name}"
         try:
@@ -395,7 +409,7 @@ def lint_executables(bundle) -> list[Finding]:
                 DecodeProgram(
                     label=label,
                     hlo=hlo,
-                    state_nbytes=state_nbytes,
+                    state_type=state_type,
                     expect_trip=int(m.group(1)) if m else None,
                 )
             )

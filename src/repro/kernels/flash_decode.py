@@ -10,7 +10,10 @@ positional maximum of the kernel's tensor usage records).
 
 Layout: q (B, KV, G, D) — G = H/KV query heads per KV head; cache
 (B, T, KV, D); lengths (B,) valid entries per row. Grid (B, KV, nT) with
-the T axis sequential ('arbitrary') so scratch carries across tiles.
+the T axis sequential ('arbitrary') so scratch carries across tiles. The
+wrapper views the cache head-major, (B, KV, T, D), so each K/V block is
+a (block_t, D) tile in its last two dimensions — the (8, 128) block rule
+Mosaic enforces on the TPU.
 """
 
 from __future__ import annotations
@@ -38,10 +41,10 @@ def _kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)  # (Tt, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)  # (Tt, D)
+    k = k_ref[0, 0].astype(jnp.float32)  # (Tt, D)
+    v = v_ref[0, 0].astype(jnp.float32)  # (Tt, D)
 
-    s = q @ k.T  # (G, Tt)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, Tt)
     length = lengths_ref[b]
     positions = t_idx * block_t + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 1
@@ -73,16 +76,18 @@ def flash_decode(
     lengths: jax.Array,  # (B,) int32 — valid cache entries per row
     *,
     block_t: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     B, KV, G, D = q.shape
     T = k_cache.shape[1]
     block_t = min(block_t, T)
     n_t = -(-T // block_t)
+    k_cache = k_cache.transpose(0, 2, 1, 3)  # (B, KV, T, D)
+    v_cache = v_cache.transpose(0, 2, 1, 3)
     if T % block_t:
         pad = n_t * block_t - T
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
     scale = 1.0 / (D ** 0.5)
     grid = (B, KV, n_t)
     out = pl.pallas_call(
@@ -93,8 +98,8 @@ def flash_decode(
             in_specs=[
                 # index maps get the prefetched scalar ref as a trailing arg
                 pl.BlockSpec((1, 1, G, D), lambda b, h, t, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_t, 1, D), lambda b, h, t, lens: (b, t, h, 0)),
-                pl.BlockSpec((1, block_t, 1, D), lambda b, h, t, lens: (b, t, h, 0)),
+                pl.BlockSpec((1, 1, block_t, D), lambda b, h, t, lens: (b, h, t, 0)),
+                pl.BlockSpec((1, 1, block_t, D), lambda b, h, t, lens: (b, h, t, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, t, lens: (b, h, 0, 0)),
             scratch_shapes=[
@@ -104,7 +109,7 @@ def flash_decode(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

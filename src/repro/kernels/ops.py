@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True in this CPU container (the kernels TARGET
-TPU; interpret mode executes the kernel body in Python for validation).
-On a real TPU runtime set ``interpret=False``.
+The kernels compile for the TPU (``interpret=False``, the default).
+Tests on the CPU pass ``interpret=True``, which runs the kernel body in
+the Pallas interpreter.
 """
 
 from __future__ import annotations

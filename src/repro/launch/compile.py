@@ -87,6 +87,7 @@ from repro.core.unified import (
     plan as plan_unified,
     state_records_from_pytree,
 )
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models.api import Model, ShapeSpec
 from repro.trace.jaxpr_liveness import trace_graph
 
@@ -193,12 +194,8 @@ def _measure_xla_temp(
     allocation, so bundle-served engines keep the planned-vs-XLA
     validation line without compiling anything at serving time."""
     decode, specs = _decode_specs(cfg, n_slots=n_slots, max_len=max_len)
-    try:
-        compiled = jax.jit(decode).lower(*specs).compile()
-        ma = compiled.memory_analysis()
-        return int(getattr(ma, "temp_size_in_bytes", 0)) or None
-    except Exception:
-        return None
+    compiled = jax.jit(decode).lower(*specs).compile()
+    return compiled.memory_analysis().temp_size_in_bytes or None
 
 
 def compile_decode_plan(
@@ -538,6 +535,7 @@ def main() -> None:
     args = ap.parse_args()
     if bool(args.arch) == bool(args.all):
         ap.error("pass exactly one of --arch or --all")
+    enable_compile_cache()
 
     command = shlex.join(sys.argv)
     if args.all:

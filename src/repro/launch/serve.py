@@ -43,6 +43,7 @@ import numpy as np
 from repro.configs.base import ARCH_IDS, get_config, get_reduced
 from repro.core.shared_objects import from_page_log, from_slot_log
 from repro.core.unified import PlanSession
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models.api import Model
 from repro.runtime.engine import InferenceEngine
 
@@ -120,6 +121,7 @@ def run(argv: list[str] | None = None) -> dict:
                     help="physical pool page count for --page-size "
                          "(default: slots x pages-per-slot)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_reduced(args.arch)
     if cfg.family == "audio":
@@ -256,6 +258,7 @@ def run(argv: list[str] | None = None) -> dict:
         "requests": len(done),
         "tokens": toks,
         "tokens_per_request": {r.request_id: list(r.tokens) for r in done},
+        "prompts": {r.request_id: r.prompt.tolist() for r in done},
         "waves": engine._wave,
         "tokens_per_s": toks / wall if wall > 0 else None,
         "host_syncs": host_syncs,

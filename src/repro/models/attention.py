@@ -256,12 +256,13 @@ def attn_decode_kernel(
     eps: float = 1e-6,
     constrain: Constrain = None,
     active: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
     """attn_decode with the Pallas flash_decode kernel as the attention
     core (single-pass K/V streaming; see kernels/flash_decode.py). Global
     attention only — ring-buffer window layers need per-slot position
-    masks the kernel does not model. ``interpret=True`` on CPU."""
+    masks the kernel does not model. Tests on the CPU pass
+    ``interpret=True``."""
     from repro.kernels.flash_decode import flash_decode
 
     if window is not None:

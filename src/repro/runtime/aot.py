@@ -34,6 +34,7 @@ donation metadata rides inside the executable (audited post-publish by
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import pickle
 from typing import Any
@@ -50,6 +51,7 @@ from repro.core.artifact import (
     expected_executable_entries,
 )
 from repro.core.unified import PagedStatePlan, StatePlan
+from repro.launch.jax_cache import persistent_cache_disabled
 from repro.runtime.paging import (
     PAGED_BLOCK_DONATE,
     PAGED_DECODE_DONATE,
@@ -71,6 +73,7 @@ from repro.runtime.residency import (
     resident_block_impl,
     resident_decode_impl,
     resident_reset_impl,
+    state_buffer_aval,
 )
 from repro.runtime.sampling import SamplingParams, TokenSampler
 
@@ -122,16 +125,13 @@ def build_decode_executables(
     paged = isinstance(state_plan, PagedStatePlan)
     if paged:
         residency = PagedStateResidency(state_plan, caches, n_slots=n_slots)
-        buf = jax.ShapeDtypeStruct(
-            (state_plan.phys_total_size,), jnp.uint8
-        )
         pages = jax.ShapeDtypeStruct(
             (n_slots, state_plan.pages_per_slot), jnp.int32
         )
     else:
         residency = StateResidency(state_plan, caches, n_slots=n_slots)
-        buf = jax.ShapeDtypeStruct((state_plan.total_size,), jnp.uint8)
         pages = None
+    buf = state_buffer_aval(state_plan)
 
     tok = jax.ShapeDtypeStruct((n_slots, 1), jnp.int32)
     vec_i32 = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
@@ -141,8 +141,18 @@ def build_decode_executables(
 
     entries: dict[str, ExecutableEntry] = {}
 
+    # XLA:CPU re-serializes an executable loaded from the persistent
+    # compile cache without its function library, and the bundle then
+    # fails when run (tests/test_aot.py, warm-cache case): on the CPU
+    # these compiles skip the cache
+    skip_cache = jax.default_backend() == "cpu"
+
     def _compile(name, fn, avals, donate=()):
-        compiled = jax.jit(fn, donate_argnums=donate).lower(*avals).compile()
+        with (persistent_cache_disabled() if skip_cache
+              else contextlib.nullcontext()):
+            compiled = (
+                jax.jit(fn, donate_argnums=donate).lower(*avals).compile()
+            )
         count_compile()
         entries[name] = executable_entry(serialize_compiled(compiled))
         return compiled
@@ -211,11 +221,7 @@ def build_decode_executables(
              keys, eos),
         )
 
-    try:
-        ma = pytree_decode.memory_analysis()
-        xla_temp = int(getattr(ma, "temp_size_in_bytes", 0)) or None
-    except Exception:
-        xla_temp = None
+    xla_temp = pytree_decode.memory_analysis().temp_size_in_bytes or None
     pack = ExecutablePack(
         platform=jax.default_backend(),
         jax_version=jax.__version__,

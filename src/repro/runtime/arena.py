@@ -17,9 +17,9 @@ planner objects to materialize its memory.
 
 Two arena implementations share the layout contract: the numpy
 :class:`Arena` (host buffers — the executor's deployment path) and the
-jax :class:`DeviceArena` (one flat ``uint8`` device buffer whose views
-are carved with ``lax.dynamic_slice`` + bitcast — the engine's
-cross-step state residency, see ``runtime/residency.py``).
+jax :class:`DeviceArena` (one flat device buffer of the state's element
+dtype whose views are carved with ``lax.dynamic_slice`` + reshape — the
+engine's cross-step state residency, see ``runtime/residency.py``).
 """
 
 from __future__ import annotations
@@ -175,34 +175,74 @@ class Arena:
 class DeviceArena:
     """jax twin of :class:`Arena`: the same :class:`ArenaLayout` and the
     same bounds-checked view contract, but the backing store is a flat
-    ``uint8`` device buffer threaded *functionally* — ``store`` returns a
-    NEW buffer value instead of mutating, so it composes with jit; under
-    a donated jit argument XLA updates the one physical allocation in
+    device buffer threaded *functionally* — ``store`` returns a NEW
+    buffer value instead of mutating, so it composes with jit; under a
+    donated jit argument XLA updates the one physical allocation in
     place, which is exactly how the engine's decode step keeps the whole
     cross-step state in ONE device buffer across waves.
+
+    The buffer is typed by the one element dtype every tensor it holds
+    shares (a model's cache leaves all carry ``cfg.dtype``), and byte
+    offsets become element offsets. Views are plain slices and reshapes:
+    no byte view and no bitcast, whose ``(..., itemsize)`` minor
+    dimension the TPU pads to 128 lanes. A view or store of another
+    dtype is refused.
 
     All offsets/sizes are Python ints (the plan is static), so every
     ``dynamic_slice``/``dynamic_update_slice`` lowers to a static-index
     slice XLA can fuse or alias away.
     """
 
-    def __init__(self, layout: "ArenaLayout"):
+    def __init__(self, layout: "ArenaLayout", dtype):
         layout.validate()
         self.layout = layout
+        self.dtype = np.dtype(dtype)
         self._sizes = layout.sizes
+        item = self.dtype.itemsize
+        unaligned = sorted(
+            tid for tid, off in layout.offsets.items() if off % item
+        )
+        if layout.total_size % item or unaligned:
+            raise ValueError(
+                f"a {self.dtype.name} device arena needs offsets and a "
+                f"total size divisible by {item} B (tensors "
+                f"{unaligned[:3]}, total {layout.total_size} B)"
+            )
 
     @property
     def nbytes(self) -> int:
-        return max(self.layout.total_size, 1)
+        return self.size * self.dtype.itemsize
+
+    @property
+    def size(self) -> int:
+        """Buffer length in elements."""
+        return self.length(self.layout.total_size, self.dtype)
+
+    @staticmethod
+    def length(total_size: int, dtype) -> int:
+        """Elements of the flat buffer that holds ``total_size`` bytes of
+        ``dtype`` state, at least one. :meth:`allocate` and
+        ``residency.state_buffer_aval`` both shape the buffer from here."""
+        return max(total_size // np.dtype(dtype).itemsize, 1)
 
     def allocate(self):
         """A fresh zeroed device buffer of the arena's full size."""
         import jax.numpy as jnp
 
-        return jnp.zeros((self.nbytes,), jnp.uint8)
+        return jnp.zeros((self.size,), self.dtype)
 
-    def _check(self, tensor_id: int, nbytes: int) -> int:
+    def _check(self, tensor_id: int, shape, dtype) -> tuple[int, int]:
+        """(element offset, element count) of a ``shape``/``dtype`` view
+        of the tensor's planned slot, after the bounds contract."""
+        if np.dtype(dtype) != self.dtype:
+            raise ValueError(
+                f"tensor {tensor_id}: a {np.dtype(dtype).name} view of a "
+                f"{self.dtype.name} device arena (the arena holds one "
+                f"element dtype and never bitcasts)"
+            )
         off = self.layout.offsets[tensor_id]
+        count = int(np.prod(shape))
+        nbytes = count * self.dtype.itemsize
         # same contract as Arena.view: an oversized view would silently
         # alias the NEXT tensor's planned slot
         if nbytes > self._sizes[tensor_id]:
@@ -215,30 +255,20 @@ class DeviceArena:
                 f"tensor {tensor_id}: view [{off}, {off + nbytes}) exceeds "
                 f"arena of {self.layout.total_size} B"
             )
-        return off
+        return off // self.dtype.itemsize, count
 
     def view(self, buf, tensor_id: int, shape, dtype):
-        """Read the tensor's planned bytes out of ``buf`` as a
-        ``shape``/``dtype`` jax array (slice + bitcast + reshape)."""
+        """Read the tensor's planned slot out of ``buf`` as a
+        ``shape``/``dtype`` jax array (slice + reshape)."""
         import jax
-        import jax.numpy as jnp
 
-        dt = jnp.dtype(dtype)
-        nbytes = int(np.prod(shape)) * dt.itemsize
-        off = self._check(tensor_id, nbytes)
-        raw = jax.lax.dynamic_slice(buf, (off,), (nbytes,))
-        if dt.itemsize > 1:
-            raw = raw.reshape(-1, dt.itemsize)
-        return jax.lax.bitcast_convert_type(raw, dt).reshape(shape)
+        off, count = self._check(tensor_id, shape, dtype)
+        return jax.lax.dynamic_slice(buf, (off,), (count,)).reshape(shape)
 
     def store(self, buf, tensor_id: int, value):
-        """Return a new buffer with ``value``'s bytes at the tensor's
-        planned offset (functional twin of :meth:`Arena.store`)."""
+        """Return a new buffer with ``value`` at the tensor's planned
+        offset (functional twin of :meth:`Arena.store`)."""
         import jax
-        import jax.numpy as jnp
 
-        dt = jnp.dtype(value.dtype)
-        nbytes = int(np.prod(value.shape)) * dt.itemsize
-        off = self._check(tensor_id, nbytes)
-        raw = jax.lax.bitcast_convert_type(value, jnp.uint8).reshape(-1)
-        return jax.lax.dynamic_update_slice(buf, raw, (off,))
+        off, _ = self._check(tensor_id, value.shape, value.dtype)
+        return jax.lax.dynamic_update_slice(buf, value.reshape(-1), (off,))

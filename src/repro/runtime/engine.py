@@ -101,7 +101,6 @@ from repro.runtime.residency import (
     PytreeState,
     ResidentState,
     StateResidency,
-    count_compile,
     residency_enabled,
 )
 from repro.runtime.sampling import SamplingParams, TokenSampler, host_probs
@@ -154,6 +153,9 @@ class _Inflight:
 @dataclasses.dataclass
 class MemoryReport:
     activation_plan: MemoryPlan
+    # XLA's temp bytes for the pytree decode step, measured when the
+    # bundle was compiled (None without a bundle: the engine compiles no
+    # program just to measure it)
     xla_temp_bytes: int | None
     # exact per-slot state bytes — the StatePlan's slot region size
     # (``cache_bytes // n_slots`` used to truncate remainder bytes away)
@@ -488,24 +490,6 @@ class InferenceEngine:
             strategy = spec.strategy if spec is not None else "auto"
             plan = plan_graph(graph, mode="offsets", strategy=strategy)
             plan_source = "cache" if plan.cache_hit else "planned"
-        if bundle is None and xla_temp is None:
-            # planned-vs-XLA validation line: only a bundle carries the
-            # measurement precomputed; every other plan source (trace,
-            # spec-planned searched graph) measures it here. Measured on
-            # the plain cache-pytree decode (comparable across residency
-            # modes and to compile.py's offline measurement).
-            try:
-                compiled = (
-                    jax.jit(_decode_fn)
-                    .lower(params, tok0, cache_template, pos0, act0)
-                    .compile()
-                )
-                count_compile()
-                ma = compiled.memory_analysis()
-                xla_temp = int(getattr(ma, "temp_size_in_bytes", 0)) or None
-            except Exception:
-                pass
-
         # cross-step half: a v2 bundle ships the slot/KV layout; anything
         # else lays it out from the engine's own cache pytree (cheap, but
         # counted — unified.STATE_PLAN_CALLS — so tests can pin the
@@ -589,55 +573,41 @@ class InferenceEngine:
         self.activation_arena = Arena(act_layout)
         self.residency: StateResidency | None = None
         paged_plan = isinstance(state_plan, PagedStatePlan)
-        if residency_enabled(state_residency):
-            try:
-                if paged_plan:
-                    # page-table addressing over the physical pool
-                    # buffer; page allocation bookkeeping lives in the
-                    # backend, driven by _admit / retirement below
-                    self.residency = PagedStateResidency(
-                        state_plan, cache_template, n_slots=n_slots,
-                        layout=self.state_layout,
-                    )
-                    self.state = PagedResidentState(
-                        self.model, self.residency, executables=aot_execs
-                    )
-                else:
-                    self.residency = StateResidency(
-                        state_plan, cache_template, n_slots=n_slots,
-                        layout=self.state_layout,
-                    )
-                    # zero-init straight into the flat buffer
-                    # (init_cache's contract is all-zero state): on this
-                    # path the engine NEVER materializes a cache pytree,
-                    # so cold start holds exactly one state allocation,
-                    # not pytree + arena
-                    self.state = ResidentState(
-                        self.model, self.residency, executables=aot_execs
-                    )
-            except Exception as e:
-                # a state plan that cannot back this cache pytree must
-                # degrade to the XLA-allocated path, not kill serving
-                warnings.warn(
-                    f"state residency disabled: {e}", RuntimeWarning,
-                    stacklevel=2,
-                )
-                self.residency = None
-        if self.residency is None:
+        if not residency_enabled(state_residency):
             if paged_plan:
-                # the pytree backend has no page indirection: tokens are
-                # identical (it is the differential oracle), but state
-                # stays symmetric and page accounting is unavailable
-                warnings.warn(
-                    "paged state requires state residency; serving the "
-                    "symmetric XLA-allocated pytree backend instead",
-                    RuntimeWarning,
-                    stacklevel=2,
+                # the pytree backend has no page indirection, so serving
+                # it would silently drop the paging that was asked for
+                raise ValueError(
+                    "paged state requires state residency; serve with "
+                    "residency on, or drop page_size"
                 )
             self.state = PytreeState(
                 self.model,
                 self.model.init_cache(n_slots, self.max_len),
                 executables=aot_execs,
+            )
+        elif paged_plan:
+            # page-table addressing over the physical pool buffer; page
+            # allocation bookkeeping lives in the backend, driven by
+            # _admit / retirement below
+            self.residency = PagedStateResidency(
+                state_plan, cache_template, n_slots=n_slots,
+                layout=self.state_layout,
+            )
+            self.state = PagedResidentState(
+                self.model, self.residency, executables=aot_execs
+            )
+        else:
+            self.residency = StateResidency(
+                state_plan, cache_template, n_slots=n_slots,
+                layout=self.state_layout,
+            )
+            # zero-init straight into the flat buffer (init_cache's
+            # contract is all-zero state): on this path the engine NEVER
+            # materializes a cache pytree, so cold start holds exactly
+            # one state allocation, not pytree + arena
+            self.state = ResidentState(
+                self.model, self.residency, executables=aot_execs
             )
         paged_backend = bool(getattr(self.state, "paged", False))
         self._memory_report = MemoryReport(

@@ -11,8 +11,8 @@ physical pages (:class:`~repro.core.unified.PagedStatePlan`):
 
 * :class:`PagedStateResidency` re-binds the cache pytree to the plan
   through a page-table indirection: ``unpack`` gathers each slot's
-  logical region from its table row (``jnp.take`` over the page-reshaped
-  buffer), ``pack`` scatters it back — one gather + one scatter per
+  logical region from its table row (``jnp.take`` over the buffer's
+  page rows), ``pack`` scatters it back — one gather + one scatter per
   decode wave, all shapes static, so the decode jit stays a fixed
   program and the table is plain int32 *data* (no retrace, no
   recompile when the mapping changes);
@@ -58,6 +58,7 @@ from repro.runtime.residency import (
     StateResidency,
     _block_wave,
     _LazyJit,
+    state_buffer_aval,
 )
 
 # Donated argument positions for the paged jits (the page table rides
@@ -95,8 +96,8 @@ class PagedOutOfPagesError(RuntimeError):
 
 class PagedStateResidency(StateResidency):
     """The :class:`~repro.runtime.residency.StateResidency` binding with
-    page-table addressing: the flat buffer is ``n_pages_total`` physical
-    pages (null page at physical index 0), and every (slot, leaf) cell
+    page-table addressing: the buffer is ``n_pages_total`` rows of one
+    physical page each (null page at row 0), and every (slot, leaf) cell
     is reached by gathering the slot's table row instead of a static
     ``slot * slot_stride`` base.
 
@@ -136,6 +137,12 @@ class PagedStateResidency(StateResidency):
             raise ValueError(
                 "paged plan's page offsets do not tile the physical pool"
             )
+        if state_plan.page_size % self.dtype.itemsize:
+            raise ValueError(
+                f"page size {state_plan.page_size} B is not a whole number "
+                f"of {self.dtype.name} elements"
+            )
+        self.page_elems = state_plan.page_size // self.dtype.itemsize
 
     @property
     def phys_total_size(self) -> int:
@@ -154,60 +161,73 @@ class PagedStateResidency(StateResidency):
                 "paged residency initializes zero state only (allocate "
                 "pages, then pack through the table)"
             )
-        return jnp.zeros(self.paged_plan.phys_total_size, jnp.uint8)
+        aval = state_buffer_aval(self.paged_plan)
+        return jnp.zeros(aval.shape, aval.dtype)
+
+    def _leaf_span(self, views) -> tuple[int, int]:
+        """(element offset, element count) of a leaf inside a slot's
+        logical region (slot 0's view offset == the leaf offset)."""
+        item = self.dtype.itemsize
+        return views[0].offset // item, views[0].used_nbytes // item
 
     def unpack(self, buf, pages) -> Any:
         """The cache pytree gathered through the page tables: ONE
-        ``jnp.take`` rebuilds every slot's logical region, then each
-        leaf is a static column slice + bitcast of it."""
-        plan = self.paged_plan
-        page, pps = plan.page_size, plan.pages_per_slot
-        buf_pages = buf.reshape(plan.n_pages_total, page)
-        region = jnp.take(buf_pages, pages.reshape(-1), axis=0).reshape(
-            self.n_slots, pps * page
-        )
+        ``jnp.take`` of every slot's page rows, then each (slot, leaf)
+        cell is a static slice + reshape of the slot's flat region."""
+        pps = self.paged_plan.pages_per_slot
+        rows = jnp.take(buf, pages.reshape(-1), axis=0)
+        regions = [
+            rows[s * pps : (s + 1) * pps].reshape(-1)
+            for s in range(self.n_slots)
+        ]
         out = []
-        for _path, axis, per_slot_shape, dt, views in self._bindings:
-            off = views[0].offset  # slot 0's view offset == leaf offset
-            nb = views[0].used_nbytes
-            raw = region[:, off : off + nb]
-            if dt.itemsize > 1:
-                raw = raw.reshape(self.n_slots, nb // dt.itemsize, dt.itemsize)
-            leaf = jax.lax.bitcast_convert_type(raw, dt)
-            leaf = leaf.reshape((self.n_slots,) + per_slot_shape)
-            out.append(jnp.moveaxis(leaf, 0, axis))
+        for _path, axis, per_slot_shape, _dt, views in self._bindings:
+            off, n = self._leaf_span(views)
+            out.append(jnp.stack(
+                [r[off : off + n].reshape(per_slot_shape) for r in regions],
+                axis=axis,
+            ))
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
     def pack(self, caches: Any, buf, pages):
         """Scatter a cache pytree back through the page tables; returns
-        the successor buffer value. Rows of unmapped logical pages all
-        target the null page and provably carry zeros (see module
-        docstring), so the duplicate scatter indices there are benign —
-        and the null page stays all-zero by the same argument."""
-        plan = self.paged_plan
-        page, pps = plan.page_size, plan.pages_per_slot
+        the successor buffer value. Each slot's flat region is its
+        leaves in plan order with zeros in the alignment gaps and the
+        tail. Rows of unmapped logical pages all target the null page
+        and provably carry zeros (see module docstring), so the
+        duplicate scatter indices there are benign — and the null page
+        stays all-zero by the same argument."""
+        pps = self.paged_plan.pages_per_slot
         leaves, treedef = jax.tree_util.tree_flatten_with_path(caches)
         if treedef != self.treedef:
             raise ValueError(
                 "decode returned a cache pytree with a different structure "
                 "than the bound template"
             )
-        region = jnp.zeros((self.n_slots, pps * page), jnp.uint8)
-        for (_, leaf), (_path, axis, _pss, dt, views) in zip(
-            leaves, self._bindings
-        ):
-            off = views[0].offset
-            nb = views[0].used_nbytes
-            flat = jnp.moveaxis(leaf, axis, 0).reshape(self.n_slots, -1)
-            raw = jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(
-                self.n_slots, nb
-            )
-            region = region.at[:, off : off + nb].set(raw)
-        buf_pages = buf.reshape(plan.n_pages_total, page)
-        buf_pages = buf_pages.at[pages.reshape(-1)].set(
-            region.reshape(self.n_slots * pps, page)
+        spans = sorted(
+            (
+                self._leaf_span(views) + (leaf, axis)
+                for (_, leaf), (_path, axis, _pss, _dt, views) in zip(
+                    leaves, self._bindings
+                )
+            ),
+            key=lambda span: span[0],
         )
-        return buf_pages.reshape(-1)
+        slot_rows = []
+        for s in range(self.n_slots):
+            pieces, end = [], 0
+            for off, n, leaf, axis in spans:
+                pieces.append(jnp.zeros((off - end,), self.dtype))
+                pieces.append(
+                    jax.lax.index_in_dim(leaf, s, axis, keepdims=False)
+                    .reshape(-1)
+                )
+                end = off + n
+            pieces.append(jnp.zeros((pps * self.page_elems - end,), self.dtype))
+            slot_rows.append(
+                jnp.concatenate(pieces).reshape(pps, self.page_elems)
+            )
+        return buf.at[pages.reshape(-1)].set(jnp.concatenate(slot_rows))
 
 
 # ------------------------------------------------- jitted decode functions

@@ -10,10 +10,10 @@ comes from *owning* the physical buffers, not modeling them):
 * :class:`StateResidency` binds a cache pytree *structure* to a
   :class:`~repro.core.unified.StatePlan`: every (slot, leaf) cell is
   addressed by the plan's :meth:`~repro.core.unified.StatePlan.leaf_view_spec`
-  and carved out of one flat ``uint8`` buffer through a
-  :class:`~repro.runtime.arena.DeviceArena` (``lax.dynamic_slice`` +
-  bitcast views on read, ``dynamic_update_slice`` on write — all static
-  offsets, fully fusible);
+  and carved out of one flat buffer of the cache's element dtype through
+  a :class:`~repro.runtime.arena.DeviceArena` (``lax.dynamic_slice`` +
+  reshape views on read, ``dynamic_update_slice`` on write — all static
+  offsets, fully fusible, no lane-padded byte views on the TPU);
 * :class:`ResidentState` is the serving backend built on it: the decode
   and slot-reset jits take the flat state buffer as a DONATED argument
   and return its successor, so XLA reuses the same physical allocation
@@ -53,10 +53,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.artifact import block_entry_name
-from repro.core.unified import StatePlan
+from repro.core.unified import PagedStatePlan, StatePlan
 from repro.runtime.arena import Arena, ArenaLayout, DeviceArena
 
-# Decode-path XLA compiles (lazy jit cache misses + explicit AOT/measure
+# Decode-path XLA compiles (lazy jit cache misses + explicit AOT
 # compiles via count_compile). NOT a count of every backend compilation
 # the process ever does — eager-op warmup and host-side utility jits are
 # out of scope; this counts the serving-path decode functions the v3
@@ -66,9 +66,8 @@ COMPILE_CALLS = 0
 
 
 def count_compile(n: int = 1) -> None:
-    """Charge ``n`` decode-path XLA compiles (AOT builds and the engine's
-    xla_temp measurement compile call this explicitly; lazy jits are
-    counted by :class:`_LazyJit`)."""
+    """Charge ``n`` decode-path XLA compiles (AOT builds call this
+    explicitly; lazy jits are counted by :class:`_LazyJit`)."""
     global COMPILE_CALLS
     COMPILE_CALLS += n
 
@@ -78,30 +77,16 @@ class _LazyJit:
 
     A call that misses the jit cache compiles; one that hits does not.
     The cache-size delta is the exact signal (``_cache_size`` is
-    jax-private but pinned by our CI smoke; when absent we degrade to
-    charging the first call, which is right for the fixed-shape serving
-    loop where each jit compiles at most once)."""
+    jax-private; the installed jax has it, and the zero-compile tests
+    fail loudly if a release drops it)."""
 
     def __init__(self, fn: Callable, **jit_kwargs: Any):
         self._jitted = jax.jit(fn, **jit_kwargs)
-        self._called = False
-
-    def _cache_size(self) -> int | None:
-        try:
-            return int(self._jitted._cache_size())
-        except Exception:
-            return None
 
     def __call__(self, *args: Any) -> Any:
-        before = self._cache_size()
+        before = self._jitted._cache_size()
         out = self._jitted(*args)
-        after = self._cache_size()
-        if before is None or after is None:
-            if not self._called:
-                count_compile()
-        elif after > before:
-            count_compile(after - before)
-        self._called = True
+        count_compile(self._jitted._cache_size() - before)
         return out
 
 
@@ -121,6 +106,24 @@ def residency_enabled(override: bool | None = None) -> bool:
         return override
     val = os.environ.get("REPRO_STATE_RESIDENCY", "on").strip().lower()
     return val not in ("off", "0", "false", "no")
+
+
+def state_buffer_aval(state_plan: StatePlan) -> jax.ShapeDtypeStruct:
+    """The device buffer that holds ``state_plan``'s state, typed by the
+    cache's element dtype: flat for a symmetric plan, one row per
+    physical page for a paged plan (so the page-table gather and scatter
+    never reshape the whole buffer). The AOT lowering and the decode lint
+    take the buffer's shape from here; the flat length is
+    :meth:`DeviceArena.length`, which the resident backend allocates."""
+    dtype = jnp.dtype(state_plan.leaves[0].dtype)
+    if isinstance(state_plan, PagedStatePlan):
+        return jax.ShapeDtypeStruct(
+            (state_plan.n_pages_total, state_plan.page_size // dtype.itemsize),
+            dtype,
+        )
+    return jax.ShapeDtypeStruct(
+        (DeviceArena.length(state_plan.total_size, dtype),), dtype
+    )
 
 
 def _slot_axis(keypath) -> int:
@@ -165,7 +168,6 @@ class StateResidency:
         # from this plan pass it in; from_state_plan re-validates
         if layout is None:
             layout = ArenaLayout.from_state_plan(state_plan)
-        self.arena = DeviceArena(layout)
 
         leaves, self.treedef = jax.tree_util.tree_flatten_with_path(template)
         views_by_path: dict[str, list] = {}
@@ -210,6 +212,14 @@ class StateResidency:
                         f"{per_slot_nbytes} B/slot"
                     )
             self._bindings.append((path, axis, per_slot_shape, dt, views))
+        dtypes = sorted({dt.name for _p, _a, _s, dt, _v in self._bindings})
+        if len(dtypes) != 1:
+            raise ValueError(
+                f"state residency holds one element dtype per buffer; "
+                f"this cache pytree mixes {dtypes}"
+            )
+        self.dtype = self._bindings[0][3]
+        self.arena = DeviceArena(layout, self.dtype)
 
     @property
     def total_size(self) -> int:
@@ -244,7 +254,7 @@ class StateResidency:
         # jnp.array COPIES into a device-owned buffer: device_put of the
         # host arena can zero-copy alias numpy memory on CPU, which is
         # unsafe to donate through the decode jits
-        return jnp.array(host.buf)
+        return jnp.array(host.buf[: self.arena.nbytes].view(self.dtype))
 
     def unpack(self, buf) -> Any:
         """The cache pytree as views over ``buf`` — every leaf rebuilt

@@ -142,6 +142,47 @@ def test_aot_block_path_zero_compile_and_identical(cfg, params, tmp_path):
     assert tokens == _serve(lazy, max_new=4)
 
 
+def test_bundle_compiled_with_the_compile_cache_on_serves(
+    cfg, params, bundle_dir, tmp_path
+):
+    """A bundle compiled twice with the persistent compile cache on (the
+    second compile could read every decode program back from it) still
+    serves, with zero compiles and the tokens of a bundle compiled with
+    the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        for k, v in zip(keys, (str(tmp_path / "xla"), True, 0, 0)):
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+        for name in ("cold", "warm"):
+            compile_and_publish(
+                cfg, tmp_path / name, n_slots=N_SLOTS, max_len=MAX_LEN,
+                measure_xla=False,
+            )
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    c0 = residency.COMPILE_CALLS
+    warm = InferenceEngine(
+        cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN,
+        session=PlanSession.from_manifest(tmp_path / "warm"),
+    )
+    assert warm.memory_report.aot_warning is None
+    tokens = _serve(warm)
+    assert residency.COMPILE_CALLS - c0 == 0
+    ref = InferenceEngine(
+        cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN,
+        session=PlanSession.from_manifest(bundle_dir),
+    )
+    assert tokens == _serve(ref)
+
+
 def test_v2_bundle_degrades_to_lazy_compile(cfg, params, bundle, tmp_path):
     """Satellite: a v2 document still serves its PLANS from the bundle —
     only the executables are missing, so the engine pays lazy compiles

@@ -11,7 +11,7 @@ import pytest
 from repro.analysis import decode_lint
 from repro.analysis.decode_lint import DecodeProgram, parse_alias_table
 
-STATE = 1024  # synthetic state-buffer size
+STATE = "u8[1024]"  # synthetic state-buffer type
 
 
 def _module(body: str, *, alias: str = "{ {1}: (2, {}, may-alias) }") -> str:
@@ -47,20 +47,20 @@ def test_parse_alias_table():
 
 
 def test_clean_program_passes():
-    prog = DecodeProgram(label="t:step", hlo=_module(""), state_nbytes=STATE)
+    prog = DecodeProgram(label="t:step", hlo=_module(""), state_type=STATE)
     assert decode_lint.lint_program(prog) == []
 
 
 def test_state_not_donated():
     prog = DecodeProgram(
-        label="t:step", hlo=_module("", alias=""), state_nbytes=STATE
+        label="t:step", hlo=_module("", alias=""), state_type=STATE
     )
     assert _codes(decode_lint.lint_program(prog)) == {"state-not-donated"}
 
 
 def test_state_param_missing():
     prog = DecodeProgram(
-        label="t:step", hlo=_module(""), state_nbytes=STATE + 1
+        label="t:step", hlo=_module(""), state_type="u8[1025]"
     )
     assert "state-param-missing" in _codes(decode_lint.lint_program(prog))
 
@@ -72,7 +72,7 @@ def test_host_transfer_codes():
             "  %tok = token[] after-all()\n"
             "  %of = token[] outfeed(%p0, %tok), outfeed_shape=f32[4]\n"
         ),
-        state_nbytes=STATE,
+        state_type=STATE,
     )
     assert "host-transfer" in _codes(decode_lint.lint_program(prog))
 
@@ -81,14 +81,14 @@ def test_host_transfer_codes():
         hlo=_module(
             '  %cc = f32[4]{0} custom-call(%p0), custom_call_target="MoveToHost"\n'
         ),
-        state_nbytes=STATE,
+        state_type=STATE,
     )
     assert "host-transfer" in _codes(decode_lint.lint_program(prog))
 
     prog = DecodeProgram(
         label="t:step",
         hlo=_module("  %h = f32[4]{0:S(5)} copy(%p0)\n"),
-        state_nbytes=STATE,
+        state_type=STATE,
     )
     assert "host-transfer" in _codes(decode_lint.lint_program(prog))
 
@@ -97,7 +97,7 @@ def test_whole_buffer_copy_is_warning_and_fusion_internal_is_exempt():
     prog = DecodeProgram(
         label="t:step",
         hlo=_module("  %cp = u8[1024]{0} copy(%p2)\n"),
-        state_nbytes=STATE,
+        state_type=STATE,
     )
     findings = decode_lint.lint_program(prog)
     assert _codes(findings) == {"state-buffer-copy"}
@@ -121,7 +121,7 @@ def test_whole_buffer_copy_is_warning_and_fusion_internal_is_exempt():
         "  ROOT %tuple.1 = (f32[4]{0}, u8[1024]{0}) tuple(%p0, %fu)\n"
         "}\n"
     )
-    prog = DecodeProgram(label="t:step", hlo=fused, state_nbytes=STATE)
+    prog = DecodeProgram(label="t:step", hlo=fused, state_type=STATE)
     assert decode_lint.lint_program(prog) == []
 
 
@@ -152,7 +152,7 @@ def test_scan_shape_codes():
         hlo=_while_module(
             trip_attr=', backend_config={"known_trip_count":{"n":"4"}}'
         ),
-        state_nbytes=STATE,
+        state_type=STATE,
         expect_trip=4,
     )
     assert decode_lint.lint_program(good) == []
@@ -162,7 +162,7 @@ def test_scan_shape_codes():
         hlo=_while_module(
             trip_attr=', backend_config={"known_trip_count":{"n":"8"}}'
         ),
-        state_nbytes=STATE,
+        state_type=STATE,
         expect_trip=4,
     )
     assert "scan-trip-mismatch" in _codes(decode_lint.lint_program(mismatch))
@@ -170,7 +170,7 @@ def test_scan_shape_codes():
     unknown = DecodeProgram(
         label="t:block4",
         hlo=_while_module(trip_attr=""),
-        state_nbytes=STATE,
+        state_type=STATE,
         expect_trip=4,
     )
     f = decode_lint.lint_program(unknown)
@@ -180,14 +180,14 @@ def test_scan_shape_codes():
     unrolled = DecodeProgram(
         label="t:block4",
         hlo=_module(""),
-        state_nbytes=STATE,
+        state_type=STATE,
         expect_trip=4,
     )
     assert "scan-unrolled" in _codes(decode_lint.lint_program(unrolled))
 
 
 def test_unparseable_hlo():
-    prog = DecodeProgram(label="t:step", hlo="not hlo", state_nbytes=STATE)
+    prog = DecodeProgram(label="t:step", hlo="not hlo", state_type=STATE)
     assert _codes(decode_lint.lint_program(prog)) == {"hlo-unparseable"}
 
 

@@ -32,7 +32,10 @@ from repro.optim import adamw
 
 arch = {arch!r}
 cfg = get_reduced(arch)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh(
+    (2, 4), ("data", "model"),
+    axis_types=(jax.sharding.AxisType.Auto,) * 2,
+)
 ctx = ShardingCtx(mesh, cfg)
 model = Model.for_config(cfg)
 shape = ShapeSpec("small_train", seq_len=32, global_batch=4, kind="train")

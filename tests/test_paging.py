@@ -29,6 +29,7 @@ from repro.core.unified import (
     plan_state,
     state_plan_from_obj,
     state_plan_to_obj,
+    state_records_from_pytree,
 )
 from repro.models.api import Model
 from repro.runtime.engine import InferenceEngine
@@ -194,6 +195,23 @@ def test_paged_seeded_sampling_matches_symmetric():
         paged, b = _run(cfg, params, prompts, page_size=1024, **kw, **extra)
         assert a == b, f"sampled trajectory diverged under paging ({extra})"
         _assert_byte_identical(sym, paged)
+
+
+def test_page_size_must_hold_whole_elements():
+    """The pool is one row of cache elements per page, so a page size
+    that splits an element is refused when the backend binds."""
+    from repro.runtime.paging import PagedStateResidency
+
+    cfg = get_reduced("qwen3-0.6b")
+    model = Model.for_config(cfg)
+    caches = jax.eval_shape(lambda: model.init_cache(2, 16))
+    sp = plan_paged_state(
+        state_records_from_pytree(caches, n_slots=2), n_slots=2,
+        max_len=16, page_size=1002,
+        axes=detect_state_axes(model.init_cache, n_slots=2, max_len=16),
+    )
+    with pytest.raises(ValueError, match="whole number"):
+        PagedStateResidency(sp, caches, n_slots=2)
 
 
 def test_slot_reuse_frees_and_recycles_pages():
@@ -408,11 +426,16 @@ def test_paged_meta_mismatch_is_linted(tmp_path):
 
 
 def test_residency_off_falls_back_to_symmetric_with_warning():
+    """Paging with residency off is refused: serving the symmetric pytree
+    backend instead would silently drop the paging that was asked for.
+    Residency off without paging still serves the pytree backend."""
     cfg = get_reduced("qwen3-0.6b")
     params = _params(cfg)
-    with pytest.warns(RuntimeWarning, match="paged state requires"):
-        engine = InferenceEngine(cfg, params, n_slots=2, max_len=64,
-                                 page_size=1024, state_residency=False)
+    with pytest.raises(ValueError, match="paged state requires"):
+        InferenceEngine(cfg, params, n_slots=2, max_len=64,
+                        page_size=1024, state_residency=False)
+    engine = InferenceEngine(cfg, params, n_slots=2, max_len=64,
+                             state_residency=False)
     assert not getattr(engine.state, "paged", False)
     engine.submit(_prompts(cfg, sizes=(4,))[0], max_new_tokens=4)
     assert len(engine.run_until_done()) == 1

@@ -91,9 +91,10 @@ def test_state_layout_cells_are_disjoint():
 
 def test_device_arena_store_view_round_trip():
     _, _, _, _, sp = _setup("qwen3-0.6b")
-    arena = DeviceArena(ArenaLayout.from_state_plan(sp))
+    arena = DeviceArena(ArenaLayout.from_state_plan(sp), jnp.float32)
     buf = arena.allocate()
     assert buf.nbytes == sp.total_size
+    assert buf.dtype == jnp.float32 and buf.ndim == 1
     view = sp.leaf_view_spec()[0]
     n = view.used_nbytes // 4
     value = jnp.arange(n, dtype=jnp.float32)
@@ -103,9 +104,12 @@ def test_device_arena_store_view_round_trip():
     # and other cells stayed zero
     other = sp.leaf_view_spec()[1]
     rest = arena.view(
-        buf, other.tensor_id, (other.used_nbytes,), jnp.uint8
+        buf, other.tensor_id, (other.used_nbytes // 4,), jnp.float32
     )
-    assert int(np.asarray(rest).sum()) == 0
+    assert float(np.abs(np.asarray(rest)).sum()) == 0
+    # one element dtype per arena: a byte view would need a bitcast
+    with pytest.raises(ValueError, match="never bitcasts"):
+        arena.view(buf, view.tensor_id, (view.used_nbytes,), jnp.uint8)
 
 
 def test_device_arena_enforces_the_same_bounds_contract_as_arena():
@@ -113,17 +117,19 @@ def test_device_arena_enforces_the_same_bounds_contract_as_arena():
     arena — a too-large view would silently alias the next slot."""
     _, _, _, _, sp = _setup("qwen3-0.6b")
     layout = ArenaLayout.from_state_plan(sp)
-    device, host = DeviceArena(layout), Arena(layout)
+    device, host = DeviceArena(layout, jnp.float32), Arena(layout)
     view = sp.leaf_view_spec()[0]
     too_big = view.slot_nbytes + 64
     with pytest.raises(ValueError, match="exceeds planned"):
-        device.view(device.allocate(), view.tensor_id, (too_big,), jnp.uint8)
+        device.view(
+            device.allocate(), view.tensor_id, (too_big // 4,), jnp.float32
+        )
     with pytest.raises(ValueError, match="exceeds planned"):
         host.view(view.tensor_id, (too_big,), np.uint8)
     with pytest.raises(ValueError, match="exceeds planned"):
         device.store(
             device.allocate(), view.tensor_id,
-            jnp.zeros((too_big,), jnp.uint8),
+            jnp.zeros((too_big // 4,), jnp.float32),
         )
 
 
@@ -174,6 +180,21 @@ def test_residency_rejects_foreign_plans_and_templates():
     )
     with pytest.raises(ValueError, match="dtype"):
         StateResidency(bad, caches, n_slots=2)
+
+
+def test_residency_refuses_mixed_dtype_caches():
+    """One buffer holds one element dtype: the device views are slices
+    and reshapes, never bitcasts, so a cache pytree that mixes dtypes is
+    refused at binding time."""
+    caches = {
+        "k": jnp.zeros((2, 8), jnp.float32),
+        "v": jnp.zeros((2, 8), jnp.bfloat16),
+    }
+    sp = plan_state(
+        state_records_from_pytree(caches, n_slots=2), n_slots=2, max_len=8
+    )
+    with pytest.raises(ValueError, match="mixes"):
+        StateResidency(sp, caches, n_slots=2)
 
 
 # --------------------------------------- satellite: layout failure modes
@@ -313,7 +334,7 @@ def test_engine_live_state_bytes_equal_planned():
     assert rep.state_live_bytes == rep.state_planned_bytes
     assert rep.state_live_bytes == rep.state_plan.total_size
     assert engine.state.live_bytes == rep.state_plan.total_size
-    assert engine.state.buf.dtype == jnp.uint8
+    assert engine.state.buf.dtype == jnp.dtype(cfg.dtype)
     assert "state residency: ON" in rep.summary()
     # the per-slot figure is the exact plan region size, not a truncating
     # integer division of measured bytes
