@@ -1,0 +1,6 @@
+"""Device: the share of the traced window in which no operation ran on
+the device, in percent."""
+
+
+def read(ctx):
+    return None if ctx.trace is None else 100.0 * ctx.trace.idle_share
