@@ -1,11 +1,11 @@
-"""One registry over the process-wide instrumentation counters.
+"""One registry over the process-wide instrumentation: counters and spans.
 
 The repo instruments its hot paths with module-global counters
 (``tracer.TRACE_CALLS``, ``planner.PLAN_CALLS``,
 ``unified.STATE_PLAN_CALLS``, ``engine.HOST_SYNCS``,
-``residency.COMPILE_CALLS``) that tests, CI and
-benches snapshot/delta to pin caching and sync behaviour. Before this
-module each call site hand-rolled the same
+``residency.COMPILE_CALLS``, ``residency.DECODE_DISPATCHES``) that
+tests, CI and benches snapshot/delta to pin caching and sync behaviour.
+Before this module each call site hand-rolled the same
 ``t0, p0, s0 = tracer.TRACE_CALLS, planner.PLAN_CALLS, ...`` boilerplate;
 here they are one named registry:
 
@@ -16,8 +16,16 @@ here they are one named registry:
     assert cap.delta("trace_calls") == 0
     assert cap.delta("host_syncs") == 1
 
-Counters are looked up lazily by (module, attribute) so importing this
-module does not drag in jax via ``repro.runtime.engine``.
+Host spans of the serving path go through :func:`span`, a named
+interval on the profiler's host timeline, which shares its clock with
+the device trace:
+
+    with counters.span("repro.prompt_feed", rid=7, tokens=31):
+        ...
+
+Counters are looked up lazily by (module, attribute), and the profiler
+is imported on first use, so importing this module does not drag in
+jax via ``repro.runtime.engine``.
 """
 
 from __future__ import annotations
@@ -33,6 +41,10 @@ REGISTRY: dict[str, tuple[str, str]] = {
     "state_plan_calls": ("repro.core.unified", "STATE_PLAN_CALLS"),
     "host_syncs": ("repro.runtime.engine", "HOST_SYNCS"),
     "compile_calls": ("repro.runtime.residency", "COMPILE_CALLS"),
+    # one per execution of the single-wave decode program, prompt-feed
+    # dispatches included (scan blocks are not counted). HOST_SYNCS
+    # still counts host-loop waves and scan blocks only, not prompt feed.
+    "decode_dispatches": ("repro.runtime.residency", "DECODE_DISPATCHES"),
 }
 
 
@@ -57,6 +69,16 @@ def reset(names: tuple[str, ...] | None = None) -> None:
     for n in names or tuple(REGISTRY):
         _, attr = REGISTRY[n]
         setattr(_module(n), attr, 0)
+
+
+def span(name: str, **args):
+    """A host span for ``with``: ``jax.profiler.TraceAnnotation`` under
+    ``name``, its keyword arguments written as the event's stats. The
+    serving path's spans are named ``repro.*``. Outside a profiler
+    session the span records nothing and costs about a microsecond."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **args)
 
 
 class Capture:

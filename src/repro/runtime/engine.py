@@ -62,6 +62,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import time
 import warnings
 from pathlib import Path
 from typing import Any
@@ -70,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import counters
 from repro.configs.base import ArchConfig
 from repro.core.artifact import (
     PlanBundle,
@@ -110,7 +112,8 @@ from repro.trace.jaxpr_liveness import trace_graph
 # counter discipline as tracer.TRACE_CALLS / planner.PLAN_CALLS /
 # unified.STATE_PLAN_CALLS): +1 per host-loop wave, +1 per scan block —
 # CI pins host syncs per scan block to exactly 1. Prefill dispatches are
-# not counted (they are per-prompt-token by construction).
+# not counted (they are per-prompt-token by construction; the registry's
+# ``decode_dispatches`` counts every decode-program execution).
 HOST_SYNCS = 0
 
 
@@ -132,6 +135,10 @@ class Request:
     admitted_wave: int = -1  # wave at which the request took a slot
     tokens: list[int] = dataclasses.field(default_factory=list)
     finished_wave: int = -1
+    # host clock (time.perf_counter) at submit, and when the request took
+    # a slot, before its prompt feed (None while queued)
+    submitted_s: float | None = None
+    admitted_s: float | None = None
 
 
 @dataclasses.dataclass
@@ -659,7 +666,7 @@ class InferenceEngine:
         self._next_rid += 1
         self._queue.append(
             Request(rid, np.asarray(prompt, np.int32), max_new_tokens,
-                    arrived_wave=self._wave)
+                    arrived_wave=self._wave, submitted_s=time.perf_counter())
         )
         return rid
 
@@ -715,9 +722,12 @@ class InferenceEngine:
             jnp.array(pos, jnp.int32), jnp.array(active),
         )
 
-    def _admit(self) -> None:
+    def _take_slots(self) -> list[tuple[int, Request]]:
+        """Give queued requests the free slots, in FIFO order; returns
+        the ``(slot, request)`` pairs admitted."""
         free = [s for s in range(self.n_slots) if s not in self._active]
         paged = getattr(self.state, "paged", False)
+        taken: list[tuple[int, Request]] = []
         while free and self._queue:
             if paged:
                 # allocate-before-admit: map the pages the head request
@@ -745,22 +755,38 @@ class InferenceEngine:
             slot = free.pop(0)
             req = self._queue.pop(0)
             req.admitted_wave = self._wave
+            req.admitted_s = time.perf_counter()
             self._active[slot] = req
-            # per-slot prefill: feed prompt tokens through the decode step
-            # at this slot's own position; other slots are NOT advanced
-            # (their position/token stay put -> the scatter rewrites their
-            # current cache entry with identical values: idempotent).
-            self._slot_pos[slot] = 0
-            only_this = np.zeros(self.n_slots, bool)
-            only_this[slot] = True
-            # wipe the recycled slot's state (stale SSM state would leak);
-            # the backend copies the keep mask — see _step_tokens race note
-            self.state.reset(~only_this)
-            for t in req.prompt[:-1]:
-                self._slot_tokens[slot, 0] = t
-                self._step_tokens(self._slot_tokens, self._slot_pos, only_this)
-                self._slot_pos[slot] += 1
-            self._slot_tokens[slot, 0] = req.prompt[-1]
+            taken.append((slot, req))
+        return taken
+
+    def _admit(self) -> None:
+        taken = self._take_slots()
+        if not taken:
+            return
+        with counters.span("repro.admit", admitted=len(taken)):
+            for slot, req in taken:
+                # per-slot prefill: feed prompt tokens through the decode
+                # step at this slot's own position; other slots are NOT
+                # advanced (their position/token stay put -> the scatter
+                # rewrites their current cache entry with identical
+                # values: idempotent).
+                self._slot_pos[slot] = 0
+                only_this = np.zeros(self.n_slots, bool)
+                only_this[slot] = True
+                # wipe the recycled slot's state (stale SSM state would
+                # leak); the backend copies the keep mask — see
+                # _step_tokens race note
+                self.state.reset(~only_this)
+                with counters.span("repro.prompt_feed", rid=req.request_id,
+                                   tokens=len(req.prompt) - 1):
+                    for t in req.prompt[:-1]:
+                        self._slot_tokens[slot, 0] = t
+                        self._step_tokens(
+                            self._slot_tokens, self._slot_pos, only_this
+                        )
+                        self._slot_pos[slot] += 1
+                self._slot_tokens[slot, 0] = req.prompt[-1]
 
     def _sample_token(self, row: np.ndarray) -> int:
         """Greedy argmax, or a draw from the engine-owned generator (so
@@ -786,7 +812,14 @@ class InferenceEngine:
 
     # ------------------------------------------------------------ serve
     def step(self) -> list[Request]:
-        """One decode wave over all active slots; returns finished reqs."""
+        """One decode wave over all active slots; returns finished reqs.
+        Host spans: ``repro.step`` (``active``: slots holding a request
+        as the step starts) around it all, then ``repro.admit`` and the
+        per-slot ``repro.sample`` after the wave's dispatch."""
+        with counters.span("repro.step", active=len(self._active)):
+            return self._step()
+
+    def _step(self) -> list[Request]:
         global HOST_SYNCS
         self._admit()
         if not self._active:
@@ -797,21 +830,22 @@ class InferenceEngine:
         logits = self._step_tokens(self._slot_tokens, self._slot_pos, active)
         HOST_SYNCS += 1
         finished: list[Request] = []
-        for slot, req in list(self._active.items()):
-            row = np.asarray(logits[slot])
-            nxt = self._sample_token(row)
-            req.tokens.append(nxt)
-            self._slot_tokens[slot, 0] = nxt
-            self._slot_pos[slot] += 1
-            if self._finished(req, slot, nxt):
-                req.finished_wave = self._wave
-                self.slot_log.append(
-                    (slot, req.admitted_wave, self._wave, req.request_id)
-                )
-                finished.append(req)
-                del self._active[slot]
-                if getattr(self.state, "paged", False):
-                    self.state.free_slot(slot, self._wave)
+        with counters.span("repro.sample", rows=len(self._active)):
+            for slot, req in list(self._active.items()):
+                row = np.asarray(logits[slot])
+                nxt = self._sample_token(row)
+                req.tokens.append(nxt)
+                self._slot_tokens[slot, 0] = nxt
+                self._slot_pos[slot] += 1
+                if self._finished(req, slot, nxt):
+                    req.finished_wave = self._wave
+                    self.slot_log.append(
+                        (slot, req.admitted_wave, self._wave, req.request_id)
+                    )
+                    finished.append(req)
+                    del self._active[slot]
+                    if getattr(self.state, "paged", False):
+                        self.state.free_slot(slot, self._wave)
         self._wave += 1
         return finished
 
@@ -939,11 +973,14 @@ class InferenceEngine:
         """One synchronous scan block: admit, dispatch K waves, absorb.
         (``run_until_done`` pipelines these — it chains the next block's
         dispatch before fetching the previous block's results whenever
-        the queue is empty.)"""
-        self._admit()
-        if not self._active:
-            return []
-        return self._absorb_block(self._dispatch_block(self._plan_block()))
+        the queue is empty.) Under the host span ``repro.step``."""
+        with counters.span("repro.step", active=len(self._active)):
+            self._admit()
+            if not self._active:
+                return []
+            return self._absorb_block(
+                self._dispatch_block(self._plan_block())
+            )
 
     def _run_blocks(self, max_waves: int) -> list[Request]:
         done: list[Request] = []

@@ -58,6 +58,8 @@ from repro.runtime.residency import (
     StateResidency,
     _block_wave,
     _LazyJit,
+    decode_and_wait,
+    scoped_call,
     state_buffer_aval,
 )
 
@@ -173,7 +175,11 @@ class PagedStateResidency(StateResidency):
     def unpack(self, buf, pages) -> Any:
         """The cache pytree gathered through the page tables: ONE
         ``jnp.take`` of every slot's page rows, then each (slot, leaf)
-        cell is a static slice + reshape of the slot's flat region."""
+        cell is a static slice + reshape of the slot's flat region. Its
+        operations carry the name scope ``state.unpack``."""
+        return scoped_call("state.unpack", self._unpack, buf, pages)
+
+    def _unpack(self, buf, pages) -> Any:
         pps = self.paged_plan.pages_per_slot
         rows = jnp.take(buf, pages.reshape(-1), axis=0)
         regions = [
@@ -196,19 +202,22 @@ class PagedStateResidency(StateResidency):
         tail. Rows of unmapped logical pages all target the null page
         and provably carry zeros (see module docstring), so the
         duplicate scatter indices there are benign — and the null page
-        stays all-zero by the same argument."""
-        pps = self.paged_plan.pages_per_slot
-        leaves, treedef = jax.tree_util.tree_flatten_with_path(caches)
-        if treedef != self.treedef:
+        stays all-zero by the same argument. Its operations carry the
+        name scope ``state.pack``."""
+        if jax.tree_util.tree_structure(caches) != self.treedef:
             raise ValueError(
                 "decode returned a cache pytree with a different structure "
                 "than the bound template"
             )
+        return scoped_call("state.pack", self._pack, caches, buf, pages)
+
+    def _pack(self, caches: Any, buf, pages):
+        pps = self.paged_plan.pages_per_slot
         spans = sorted(
             (
                 self._leaf_span(views) + (leaf, axis)
-                for (_, leaf), (_path, axis, _pss, _dt, views) in zip(
-                    leaves, self._bindings
+                for leaf, (_path, axis, _pss, _dt, views) in zip(
+                    jax.tree_util.tree_leaves(caches), self._bindings
                 )
             ),
             key=lambda span: span[0],
@@ -440,11 +449,10 @@ class PagedResidentState:
 
     # ------------------------------------------------------- serving
     def decode(self, params, tokens, pos, active):
-        logits, self.buf = self._decode(
-            params, tokens, self.buf, pos, active, self._table_dev
+        logits, self.buf = decode_and_wait(
+            self._decode, params, tokens, self.buf, pos, active,
+            self._table_dev,
         )
-        # see the _step_tokens race note in runtime/engine.py
-        jax.block_until_ready(self.buf)
         return logits
 
     def reset(self, keep):

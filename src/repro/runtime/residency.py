@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis import counters
 from repro.core.artifact import block_entry_name
 from repro.core.unified import PagedStatePlan, StatePlan
 from repro.runtime.arena import Arena, ArenaLayout, DeviceArena
@@ -65,11 +66,46 @@ from repro.runtime.arena import Arena, ArenaLayout, DeviceArena
 COMPILE_CALLS = 0
 
 
+# Executions of the single-wave decode program, one per backend
+# ``decode()`` call: host-loop waves and every prompt-feed token (the
+# registry's ``decode_dispatches``).
+DECODE_DISPATCHES = 0
+
+
 def count_compile(n: int = 1) -> None:
     """Charge ``n`` decode-path XLA compiles (AOT builds call this
     explicitly; lazy jits are counted by :class:`_LazyJit`)."""
     global COMPILE_CALLS
     COMPILE_CALLS += n
+
+
+def decode_and_wait(decode: Callable, *args) -> tuple[Any, Any]:
+    """Run one decode-program execution and wait for its new state:
+    ``decode(*args) -> (logits, state)``. Shared by every backend's
+    ``decode()``, under the host spans ``repro.state.decode`` (enqueue
+    plus wait) and ``repro.state.wait``, and counted in
+    ``DECODE_DISPATCHES``."""
+    global DECODE_DISPATCHES
+    with counters.span("repro.state.decode"):
+        logits, state = decode(*args)
+        DECODE_DISPATCHES += 1
+        # synchronize before the engine mutates its host-side buffers —
+        # see the _step_tokens race note in runtime/engine.py
+        with counters.span("repro.state.wait"):
+            jax.block_until_ready(state)
+    return logits, state
+
+
+def scoped_call(scope: str, fn: Callable, *args) -> Any:
+    """``fn(*args)`` as a call of its own in the lowered program, its
+    operations under the name scope ``scope`` (a profiler trace shows it
+    in each op's ``tf_op`` path). A name scope alone lives only in the
+    ops' metadata, which JAX's persistent compile cache leaves out of
+    its key, so a program cached before the scope existed would be found
+    and served without it; the call is part of the program text the key
+    hashes. XLA inlines the call."""
+    with jax.named_scope(scope):
+        return jax.jit(fn)(*args)
 
 
 class _LazyJit:
@@ -258,7 +294,11 @@ class StateResidency:
 
     def unpack(self, buf) -> Any:
         """The cache pytree as views over ``buf`` — every leaf rebuilt
-        from its per-slot cells at the plan's offsets."""
+        from its per-slot cells at the plan's offsets. Its operations
+        carry the name scope ``state.unpack``."""
+        return scoped_call("state.unpack", self._unpack, buf)
+
+    def _unpack(self, buf) -> Any:
         out = []
         for _path, axis, per_slot_shape, dt, views in self._bindings:
             per_slot = [
@@ -270,16 +310,18 @@ class StateResidency:
 
     def pack(self, caches: Any, buf):
         """Write a cache pytree back into ``buf`` at the plan's offsets;
-        returns the successor buffer value."""
-        leaves, treedef = jax.tree_util.tree_flatten_with_path(caches)
-        if treedef != self.treedef:
+        returns the successor buffer value. Its operations carry the
+        name scope ``state.pack``."""
+        if jax.tree_util.tree_structure(caches) != self.treedef:
             raise ValueError(
                 "decode returned a cache pytree with a different structure "
                 "than the bound template"
             )
-        for (_, leaf), (_path, axis, _pss, dt, views) in zip(
-            leaves, self._bindings
-        ):
+        return scoped_call("state.pack", self._pack, caches, buf)
+
+    def _pack(self, caches: Any, buf):
+        leaves = jax.tree_util.tree_leaves(caches)
+        for leaf, (_path, axis, _pss, dt, views) in zip(leaves, self._bindings):
             for view in views:
                 buf = self.arena.store(
                     buf, view.tensor_id, jnp.take(leaf, view.slot, axis=axis)
@@ -456,10 +498,9 @@ class ResidentState:
         self._block_jits: dict[int, Any] = {}  # scan length -> callable
 
     def decode(self, params, tokens, pos, active):
-        logits, self.buf = self._decode(params, tokens, self.buf, pos, active)
-        # synchronize before the engine mutates its host-side buffers —
-        # see the _step_tokens race note in runtime/engine.py
-        jax.block_until_ready(self.buf)
+        logits, self.buf = decode_and_wait(
+            self._decode, params, tokens, self.buf, pos, active
+        )
         return logits
 
     def reset(self, keep):
@@ -533,11 +574,9 @@ class PytreeState:
         self._block_jits: dict[int, Any] = {}  # scan length -> callable
 
     def decode(self, params, tokens, pos, active):
-        logits, self.caches = self._decode(
-            params, tokens, self.caches, pos, active
+        logits, self.caches = decode_and_wait(
+            self._decode, params, tokens, self.caches, pos, active
         )
-        # see the _step_tokens race note in runtime/engine.py
-        jax.block_until_ready(self.caches)
         return logits
 
     def reset(self, keep):
