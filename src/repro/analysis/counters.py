@@ -3,7 +3,8 @@
 The repo instruments its hot paths with module-global counters
 (``tracer.TRACE_CALLS``, ``planner.PLAN_CALLS``,
 ``unified.STATE_PLAN_CALLS``, ``engine.HOST_SYNCS``,
-``residency.COMPILE_CALLS``, ``residency.DECODE_DISPATCHES``) that
+``residency.COMPILE_CALLS``, ``residency.DECODE_DISPATCHES``,
+``engine.SAMPLE_FETCHES``) that
 tests, CI and benches snapshot/delta to pin caching and sync behaviour.
 Before this module each call site hand-rolled the same
 ``t0, p0, s0 = tracer.TRACE_CALLS, planner.PLAN_CALLS, ...`` boilerplate;
@@ -45,6 +46,9 @@ REGISTRY: dict[str, tuple[str, str]] = {
     # dispatches included (scan blocks are not counted). HOST_SYNCS
     # still counts host-loop waves and scan blocks only, not prompt feed.
     "decode_dispatches": ("repro.runtime.residency", "DECODE_DISPATCHES"),
+    # one per host-loop wave: the device-to-host fetch its sampling makes
+    # (greedy picks or the wave's logits), on either sampling path
+    "sample_fetches": ("repro.runtime.engine", "SAMPLE_FETCHES"),
 }
 
 

@@ -39,8 +39,9 @@ writes each wave's new state into the same physical allocation).
 Two serving loops share that state:
 
 * the single-wave HOST loop (``block_size=1``, the default): one decode
-  dispatch + one host sync per wave, numpy sampling on the host. This is
-  the correctness oracle;
+  dispatch + one host sync per wave, then one fetch for sampling —
+  the greedy picks, taken on the device, or the wave's logits for numpy
+  draws on the host. This is the correctness oracle;
 * the SCAN-BLOCK loop (``block_size=K``): K decode waves per dispatch via
   ``lax.scan`` over the donated state buffer, with sampling (greedy
   argmax or temperature/top-k with per-slot ``jax.random`` keys) and
@@ -105,7 +106,12 @@ from repro.runtime.residency import (
     StateResidency,
     residency_enabled,
 )
-from repro.runtime.sampling import SamplingParams, TokenSampler, host_probs
+from repro.runtime.sampling import (
+    SamplingParams,
+    TokenSampler,
+    greedy_tokens_jit,
+    host_probs,
+)
 from repro.trace.jaxpr_liveness import trace_graph
 
 # Decode-phase host synchronization points, module-wide (the same
@@ -115,6 +121,10 @@ from repro.trace.jaxpr_liveness import trace_graph
 # not counted (they are per-prompt-token by construction; the registry's
 # ``decode_dispatches`` counts every decode-program execution).
 HOST_SYNCS = 0
+# Device-to-host fetches made by the host loop's sampling: +1 per wave
+# on both the greedy and the sampled path (the rows of a wave come back
+# together, never one slot at a time).
+SAMPLE_FETCHES = 0
 
 
 class WavesExhaustedError(RuntimeError):
@@ -788,15 +798,28 @@ class InferenceEngine:
                         self._slot_pos[slot] += 1
                 self._slot_tokens[slot, 0] = req.prompt[-1]
 
-    def _sample_token(self, row: np.ndarray) -> int:
-        """Greedy argmax, or a draw from the engine-owned generator (so
-        consecutive draws — e.g. two slots in one wave — are independent,
-        while a fixed ``sample_seed`` keeps whole runs reproducible).
-        Probabilities come from the float64 ``sampling.host_probs`` —
-        the float32 softmax tripped ``Generator.choice``'s sum-to-1
-        check on rounding."""
+    def _fetch_rows(self, logits) -> np.ndarray:
+        """The wave's one device-to-host fetch for sampling, row ``slot``
+        for each slot: greedy, the device's pick (``(n_slots, 1)``
+        int32 — one argmax dispatch, ``4 * n_slots`` bytes back);
+        sampled, the whole ``(n_slots, vocab)`` logits."""
+        global SAMPLE_FETCHES
+        SAMPLE_FETCHES += 1
         if self.greedy:
-            return int(row.argmax())
+            return np.asarray(greedy_tokens_jit(logits))[:, None]
+        return np.asarray(logits)
+
+    def _sample_token(self, row: np.ndarray) -> int:
+        """One slot's next token from its row of :meth:`_fetch_rows`:
+        greedy, the pick the device already took (a one-element row);
+        sampled, a draw from the logits row with the engine-owned
+        generator (so consecutive draws — e.g. two slots in one wave —
+        are independent, while a fixed ``sample_seed`` keeps whole runs
+        reproducible). Probabilities come from the float64
+        ``sampling.host_probs`` — the float32 softmax tripped
+        ``Generator.choice``'s sum-to-1 check on rounding."""
+        if self.greedy:
+            return int(row[0])
         p = host_probs(row, temperature=self.temperature, top_k=self.top_k)
         return int(self._sampler.choice(len(p), p=p))
 
@@ -814,8 +837,9 @@ class InferenceEngine:
     def step(self) -> list[Request]:
         """One decode wave over all active slots; returns finished reqs.
         Host spans: ``repro.step`` (``active``: slots holding a request
-        as the step starts) around it all, then ``repro.admit`` and the
-        per-slot ``repro.sample`` after the wave's dispatch."""
+        as the step starts) around it all, then ``repro.admit``, and
+        ``repro.sample`` (``rows``: active slots) around the wave's
+        sampling fetch and the per-slot bookkeeping after its dispatch."""
         with counters.span("repro.step", active=len(self._active)):
             return self._step()
 
@@ -831,9 +855,9 @@ class InferenceEngine:
         HOST_SYNCS += 1
         finished: list[Request] = []
         with counters.span("repro.sample", rows=len(self._active)):
+            rows = self._fetch_rows(logits)
             for slot, req in list(self._active.items()):
-                row = np.asarray(logits[slot])
-                nxt = self._sample_token(row)
+                nxt = self._sample_token(rows[slot])
                 req.tokens.append(nxt)
                 self._slot_tokens[slot, 0] = nxt
                 self._slot_pos[slot] += 1

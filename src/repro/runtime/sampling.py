@@ -3,7 +3,8 @@
 Two halves, one contract:
 
 * the HOST half (:func:`softmax` / :func:`host_probs`) backs the
-  single-wave host loop's numpy sampling. Probabilities are computed in
+  single-wave host loop's sampled (``greedy=False``) draws, made in
+  numpy from the wave's logits fetched once. Probabilities are computed in
   float64 and explicitly renormalized — the float32 path handed
   ``Generator.choice(p=...)`` vectors whose sum drifted past numpy's
   tolerance and raised "probabilities do not sum to 1" on large vocabs;
@@ -12,6 +13,10 @@ Two halves, one contract:
   greedy argmax or temperature/top-k draws via ``jax.random.categorical``
   with per-slot PRNG keys, plus the per-wave stop bookkeeping (EOS /
   budget / max_len) that lets a whole block run without host involvement.
+
+Greedy selection is one definition for both loops: :func:`greedy_tokens`
+is traced into the scan block's sampler, and the host loop dispatches it
+once per wave (:data:`greedy_tokens_jit`) and fetches only the picks.
 
 A slot's key advances only when the slot EMITS a token, so on-device
 sampling depends only on the slot's emission index — the sampled
@@ -54,6 +59,17 @@ class SamplingParams:
             )
         if self.top_k < 0:
             raise ValueError(f"top_k must be >= 0, got {self.top_k}")
+
+
+def greedy_tokens(logits):
+    """Greedy pick of each row of ``(n_slots, vocab)`` logits: the first
+    maximal index over the vocabulary axis, as int32 — numpy's argmax tie
+    rule. Pure jax, so the scan block traces it inside its jit."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+# the host loop's one dispatch per wave; compiles once per logits shape
+greedy_tokens_jit = jax.jit(greedy_tokens)
 
 
 def softmax(x: np.ndarray) -> np.ndarray:
@@ -121,7 +137,7 @@ class TokenSampler:
         ``eos`` is a traced int32 scalar; callers with no EOS pass -1
         (never matches a vocab token)."""
         if self.params.greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            nxt = greedy_tokens(logits)
         else:
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
             sub, carried = split[:, 0], split[:, 1]
