@@ -14,10 +14,13 @@ them ahead of time from the compiled executable's HLO text:
 * **host transfers** — no outfeed/infeed/send/recv, no host memory
   space (``S(5)``) shapes, no host-placement custom-calls (error);
 * **state-buffer copies/converts** — plain ``copy``/``convert`` ops of
-  the whole state buffer's type. On the CPU backend the scan body is
-  known to emit a bounded number of full-buffer copies around its
-  nested scatter loops even with donation intact, so these report as
-  warnings with their location, not errors;
+  the whole state buffer's type. The decode program updates the
+  buffer in place (row scatters and layer stores into the loop's
+  carry), so none is expected; the CPU backend may still copy the
+  carried buffer around an in-place store inside a loop (a Mamba
+  layer's state store), where the TPU program of the same step has no
+  such copy, so these report as warnings with their location, not
+  errors;
 * **scan shape** — the block must lower to one ``while`` with the
   expected known trip count; a missing loop means XLA unrolled (and
   rematerialized) the body, a wrong count means the block traced at the
@@ -217,9 +220,10 @@ def lint_program(prog: DecodeProgram) -> list[Finding]:
                 "state-buffer-copy",
                 f"{len(copy_sites)} whole-state-buffer copy/convert op(s): "
                 f"{', '.join(copy_sites[:4])}"
-                f"{'...' if len(copy_sites) > 4 else ''} — known bounded "
-                f"CPU-backend artifact around the scan body's scatter "
-                f"loops; on an accelerator this should be zero",
+                f"{'...' if len(copy_sites) > 4 else ''} — the decode "
+                f"program updates the buffer in place, so each is a copy "
+                f"the backend added around an in-place store in a loop "
+                f"(known on the CPU; the TPU program has none)",
                 prog.label,
                 severity="warning",
             )

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import state_view
 from repro.models.layers import init_linear, init_rms, rms_norm, rope
 
 Constrain = Callable[[jax.Array, str], jax.Array] | None
@@ -214,11 +215,13 @@ def attn_decode(
 
     ``pos`` may be a vector for continuous batching: every batch row
     advances at its own position (scatter into its own cache row).
-    Rows with ``active == False`` leave their cache untouched.
+    Rows with ``active == False`` leave their cache untouched. The cache
+    leaves are arrays, or views of a state buffer
+    (``models/state_view.py``), into which only the new rows are
+    written; the new cache is then the same views.
     """
     B = x.shape[0]
-    k_cache, v_cache = cache
-    T = k_cache.shape[1]
+    T = cache[0].shape[1]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     q, k, v = _project_qkv(p, x, n_heads, n_kv, head_dim, pos_b[:, None], theta, eps)
     slot_b = pos_b % T if window is not None else jnp.minimum(pos_b, T - 1)
@@ -226,12 +229,14 @@ def attn_decode(
         slot_b = jnp.where(active, slot_b, T)  # T is OOB -> dropped
     # scatter one row per batch element (O(1) cache-bytes touched, unlike a
     # one-hot masked rewrite of the full cache)
-    rows = jnp.arange(B)
-    k_cache = k_cache.at[rows, slot_b].set(k[:, 0], mode="drop")
-    v_cache = v_cache.at[rows, slot_b].set(v[:, 0], mode="drop")
+    new_cache = (state_view.write_rows(cache[0], slot_b, k[:, 0]),
+                 state_view.write_rows(cache[1], slot_b, v[:, 0]))
+    k_cache, v_cache = map(state_view.read, new_cache)
     if constrain is not None:
         k_cache = constrain(k_cache, "kv_heads")
         v_cache = constrain(v_cache, "kv_heads")
+        if not isinstance(new_cache[0], state_view.LeafView):
+            new_cache = (k_cache, v_cache)
     if scale is None:
         scale = 1.0 / np.sqrt(head_dim)
     s = _gqa_scores(q, k_cache) * scale  # (B,H,1,T)
@@ -246,7 +251,7 @@ def attn_decode(
     s = jnp.where(mask, s, NEG_INF)
     probs = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(x.dtype)
     out = _gqa_out(probs, v_cache)  # (B,1,H*D)
-    return out @ p["wo"], (k_cache, v_cache)
+    return out @ p["wo"], new_cache
 
 
 def attn_decode_kernel(

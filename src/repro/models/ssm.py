@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import state_view
 from repro.models.layers import init_linear, init_rms, rms_norm
 
 Constrain = Callable[[jax.Array, str], jax.Array] | None
@@ -189,7 +190,7 @@ def mamba_prefill(
 def mamba_decode(
     p: dict,
     x: jax.Array,  # (B, 1, D)
-    cache: tuple[jax.Array, jax.Array],  # conv_state (B,K-1,conv_dim), ssm (B,H,P,N)
+    cache: tuple,  # conv_state (B,K-1,conv_dim), ssm (B,H,P,N): arrays or views
     *,
     expand: int,
     head_dim: int,
@@ -204,7 +205,7 @@ def mamba_decode(
     B, _, D = x.shape
     d_inner, nheads, conv_dim = ssm_dims(D, expand, head_dim, ngroups, dstate)
     dims = dict(d_inner=d_inner, nheads=nheads, ngroups=ngroups, dstate=dstate)
-    conv_state, state = cache
+    conv_state, state = map(state_view.read, cache)
     zxbcdt = x @ p["in_proj"]  # (B,1,·)
     z, xBC_new, dt = _split_proj(dims, zxbcdt)
     window = jnp.concatenate([conv_state, xBC_new], axis=1)  # (B,K,conv_dim)
@@ -231,4 +232,6 @@ def mamba_decode(
     if active is not None:
         new_state = jnp.where(active[:, None, None, None], new_state, state)
         new_conv = jnp.where(active[:, None, None], new_conv, conv_state)
-    return out, (new_conv, new_state)
+    # a cache of state-buffer views stores the new state in place
+    return out, (state_view.write(cache[0], new_conv),
+                 state_view.write(cache[1], new_state))

@@ -35,6 +35,7 @@ from repro.configs.base import ArchConfig, LayerSpec
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
+from repro.models import state_view
 from repro.models.layers import (
     activation,
     init_linear,
@@ -302,6 +303,10 @@ def _run_stack(
 ) -> tuple[jax.Array, dict | None, jax.Array]:
     shared_p = params.get("shared")
     period, n_periods, remainder = cfg.stack
+    if isinstance(caches, state_view.SlotBuffer):
+        return _run_stack_in_place(
+            params, cfg, h, emb0, constrain, caches, pos, active
+        )
 
     def period_body(carry, xs):
         h, aux = carry
@@ -344,17 +349,62 @@ def _run_stack(
     return h, out_caches, aux
 
 
+def _run_stack_in_place(
+    params: dict,
+    cfg: ArchConfig,
+    h: jax.Array,
+    emb0: jax.Array,
+    constrain: Constrain,
+    state: state_view.SlotBuffer,
+    pos: Any,
+    active: jax.Array | None,
+) -> tuple[jax.Array, state_view.SlotBuffer, jax.Array]:
+    """Decode over a :class:`~repro.models.state_view.SlotBuffer`: the
+    buffer is the period loop's carry, and each layer reads its cache as
+    views of it and writes back only what it changed. Same layers, same
+    math as the cache-pytree path of :func:`_run_stack`."""
+    shared_p = params.get("shared")
+    period, n_periods, remainder = cfg.stack
+
+    def block(lp, spec, h, slots, layer):
+        h, _, _ = _block(
+            lp, spec, cfg, h, emb0, shared_p, constrain,
+            state.views(slots, layer), pos, True, active,
+        )
+        return h
+
+    def period_body(carry, xs):
+        h, state.buf = carry
+        *layer_params, i = xs
+        for spec, lp, slots in zip(period, layer_params,
+                                   state.slots["period"]):
+            h = block(lp, spec, h, slots, i)
+        return (h, state.buf), None
+
+    if n_periods > 0 and len(period) > 0:
+        (h, state.buf), _ = jax.lax.scan(
+            period_body, (h, state.buf),
+            (*params["period"], jnp.arange(n_periods)),
+        )
+    for lp, spec, slots in zip(params["remainder"], remainder,
+                               state.slots["remainder"]):
+        h = block(lp, spec, h, slots, 0)
+    return h, state, jnp.zeros((), jnp.float32)
+
+
 def reset_slots(caches: dict, keep: jax.Array) -> dict:
     """Zero cache rows where ``keep[b]`` is False (slot recycling: stale
     SSM states / conv windows must not leak into the next request; stale
     attention entries are already hidden by position masks but are zeroed
     too for hygiene). Period caches carry batch on axis 1 (after the
-    n_periods axis), remainder caches on axis 0."""
+    n_periods axis), remainder caches on axis 0. The zeros are exact
+    (``+0``, as a fresh cache holds), not a product with 0, so every
+    backend's reset leaves the same bytes."""
 
     def mask(leaf, axis):
         shape = [1] * leaf.ndim
         shape[axis] = leaf.shape[axis]
-        return leaf * keep.astype(leaf.dtype).reshape(shape)
+        return jnp.where(keep.reshape(shape), leaf, jnp.zeros((), leaf.dtype))
 
     return {
         "period": jax.tree_util.tree_map(lambda x: mask(x, 1), caches["period"]),
@@ -456,13 +506,14 @@ def decode_step(
     params: dict,
     cfg: ArchConfig,
     token: jax.Array,  # (B, 1) int32
-    caches: dict,
+    caches: Any,  # cache pytree, or a state_view.SlotBuffer
     pos: jax.Array,  # int32 scalar or (B,) per-slot positions
     constrain: Constrain = None,
     active: jax.Array | None = None,  # (B,) continuous-batching mask
-) -> tuple[jax.Array, dict]:
+) -> tuple[jax.Array, Any]:
     """serve_step: ONE new token against the caches. Returns (logits
-    (B, vocab), new caches)."""
+    (B, vocab), new caches); a SlotBuffer is updated in place and
+    returned."""
     h = embed_tokens(params, cfg, token)
     # Zamba2's shared blocks concatenate the token's own embedding;
     # during decode that is the current token's.

@@ -174,23 +174,20 @@ class Arena:
 
 class DeviceArena:
     """jax twin of :class:`Arena`: the same :class:`ArenaLayout` and the
-    same bounds-checked view contract, but the backing store is a flat
-    device buffer threaded *functionally* — ``store`` returns a NEW
-    buffer value instead of mutating, so it composes with jit; under a
-    donated jit argument XLA updates the one physical allocation in
-    place, which is exactly how the engine's decode step keeps the whole
-    cross-step state in ONE device buffer across waves.
+    same bounds-checked view contract over a flat device buffer, which
+    the engine's decode step donates and updates in place, keeping the
+    whole cross-step state in ONE device buffer across waves (the
+    decode step writes it through ``models/state_view.py``).
 
     The buffer is typed by the one element dtype every tensor it holds
     shares (a model's cache leaves all carry ``cfg.dtype``), and byte
     offsets become element offsets. Views are plain slices and reshapes:
     no byte view and no bitcast, whose ``(..., itemsize)`` minor
-    dimension the TPU pads to 128 lanes. A view or store of another
-    dtype is refused.
+    dimension the TPU pads to 128 lanes. A view of another dtype is
+    refused.
 
     All offsets/sizes are Python ints (the plan is static), so every
-    ``dynamic_slice``/``dynamic_update_slice`` lowers to a static-index
-    slice XLA can fuse or alias away.
+    ``dynamic_slice`` lowers to a static-index slice XLA can fuse.
     """
 
     def __init__(self, layout: "ArenaLayout", dtype):
@@ -264,11 +261,3 @@ class DeviceArena:
 
         off, count = self._check(tensor_id, shape, dtype)
         return jax.lax.dynamic_slice(buf, (off,), (count,)).reshape(shape)
-
-    def store(self, buf, tensor_id: int, value):
-        """Return a new buffer with ``value`` at the tensor's planned
-        offset (functional twin of :meth:`Arena.store`)."""
-        import jax
-
-        off, _ = self._check(tensor_id, value.shape, value.dtype)
-        return jax.lax.dynamic_update_slice(buf, value.reshape(-1), (off,))

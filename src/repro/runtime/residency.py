@@ -10,15 +10,19 @@ comes from *owning* the physical buffers, not modeling them):
 * :class:`StateResidency` binds a cache pytree *structure* to a
   :class:`~repro.core.unified.StatePlan`: every (slot, leaf) cell is
   addressed by the plan's :meth:`~repro.core.unified.StatePlan.leaf_view_spec`
-  and carved out of one flat buffer of the cache's element dtype through
-  a :class:`~repro.runtime.arena.DeviceArena` (``lax.dynamic_slice`` +
-  reshape views on read, ``dynamic_update_slice`` on write — all static
-  offsets, fully fusible, no lane-padded byte views on the TPU);
+  in one flat buffer of the cache's element dtype (a
+  :class:`~repro.runtime.arena.DeviceArena`; no lane-padded byte views
+  on the TPU), and every leaf sits at one offset in every slot region,
+  which is what lets the decode step read and write it in place;
 * :class:`ResidentState` is the serving backend built on it: the decode
   and slot-reset jits take the flat state buffer as a DONATED argument
   and return its successor, so XLA reuses the same physical allocation
   every wave — live device state bytes equal ``StatePlan.total_size``
-  exactly, one allocation for the engine's whole cross-step lifecycle;
+  exactly, one allocation for the engine's whole cross-step lifecycle.
+  The decode step updates the buffer in place: the model reads each
+  layer's cache as a view of it (:meth:`StateResidency.slot_buffer`,
+  ``models/state_view.py``) and writes back only the K/V rows and SSM
+  states it changed, and a reset zeroes only the reset slots' regions;
 * :class:`PytreeState` preserves the previous XLA-allocated cache-pytree
   path behind the same interface (``REPRO_STATE_RESIDENCY=off`` escape
   hatch), which is also the baseline of the residency differential test:
@@ -55,6 +59,7 @@ import numpy as np
 from repro.analysis import counters
 from repro.core.artifact import block_entry_name
 from repro.core.unified import PagedStatePlan, StatePlan
+from repro.models.state_view import LeafSlot, SlotBuffer, scoped_call
 from repro.runtime.arena import Arena, ArenaLayout, DeviceArena
 
 # Decode-path XLA compiles (lazy jit cache misses + explicit AOT
@@ -94,18 +99,6 @@ def decode_and_wait(decode: Callable, *args) -> tuple[Any, Any]:
         with counters.span("repro.state.wait"):
             jax.block_until_ready(state)
     return logits, state
-
-
-def scoped_call(scope: str, fn: Callable, *args) -> Any:
-    """``fn(*args)`` as a call of its own in the lowered program, its
-    operations under the name scope ``scope`` (a profiler trace shows it
-    in each op's ``tf_op`` path). A name scope alone lives only in the
-    ops' metadata, which JAX's persistent compile cache leaves out of
-    its key, so a program cached before the scope existed would be found
-    and served without it; the call is part of the program text the key
-    hashes. XLA inlines the call."""
-    with jax.named_scope(scope):
-        return jax.jit(fn)(*args)
 
 
 class _LazyJit:
@@ -256,6 +249,20 @@ class StateResidency:
             )
         self.dtype = self._bindings[0][3]
         self.arena = DeviceArena(layout, self.dtype)
+        # where each leaf lives in a slot region, for the in-place decode
+        item = self.dtype.itemsize
+        self.slot_elems = state_plan.slot_stride // item
+        slots = []
+        for path, axis, per_slot_shape, _dt, views in self._bindings:
+            col = views[0].offset // item
+            if any(v.offset != v.slot * state_plan.slot_stride + col * item
+                   for v in views):
+                raise ValueError(
+                    f"state leaf {path!r}: not at one offset in every slot"
+                )
+            # a leaf stacked over the periods holds one layer per period
+            slots.append(LeafSlot(col, per_slot_shape[axis:]))
+        self.slots = jax.tree_util.tree_unflatten(self.treedef, slots)
 
     @property
     def total_size(self) -> int:
@@ -292,9 +299,15 @@ class StateResidency:
         # unsafe to donate through the decode jits
         return jnp.array(host.buf[: self.arena.nbytes].view(self.dtype))
 
+    def slot_buffer(self, buf) -> SlotBuffer:
+        """``buf`` as the decode step reads and writes it in place: each
+        layer's cache a view of the buffer at the plan's offsets."""
+        return SlotBuffer(buf, self.n_slots, self.slot_elems, self.slots)
+
     def unpack(self, buf) -> Any:
-        """The cache pytree as views over ``buf`` — every leaf rebuilt
-        from its per-slot cells at the plan's offsets. Its operations
+        """The cache pytree as copies out of ``buf`` — every leaf rebuilt
+        from its per-slot cells at the plan's offsets (for inspection;
+        the decode programs read the buffer in place). Its operations
         carry the name scope ``state.unpack``."""
         return scoped_call("state.unpack", self._unpack, buf)
 
@@ -307,26 +320,6 @@ class StateResidency:
             ]
             out.append(jnp.stack(per_slot, axis=axis))
         return jax.tree_util.tree_unflatten(self.treedef, out)
-
-    def pack(self, caches: Any, buf):
-        """Write a cache pytree back into ``buf`` at the plan's offsets;
-        returns the successor buffer value. Its operations carry the
-        name scope ``state.pack``."""
-        if jax.tree_util.tree_structure(caches) != self.treedef:
-            raise ValueError(
-                "decode returned a cache pytree with a different structure "
-                "than the bound template"
-            )
-        return scoped_call("state.pack", self._pack, caches, buf)
-
-    def _pack(self, caches: Any, buf):
-        leaves = jax.tree_util.tree_leaves(caches)
-        for leaf, (_path, axis, _pss, dt, views) in zip(leaves, self._bindings):
-            for view in views:
-                buf = self.arena.store(
-                    buf, view.tensor_id, jnp.take(leaf, view.slot, axis=axis)
-                )
-        return buf
 
 
 @dataclasses.dataclass
@@ -348,11 +341,12 @@ class BlockOut:
 
 def _block_wave(model, sampler, params, caches, tokens, pos, active, done,
                 budget, keys, eos):
-    """One scan wave, shared by both backends (only the state threading
-    differs): decode at ``active & ~done``, then the sampler's on-device
-    token selection + stop bookkeeping. Inactive/frozen slots keep their
-    token and position, so the cache scatter stays idempotent for them —
-    the same invariant the host loop relies on."""
+    """One scan wave, shared by every backend (only the state threading
+    differs: a cache pytree, or a SlotBuffer updated in place): decode
+    at ``active & ~done``, then the sampler's on-device token selection
+    + stop bookkeeping. Inactive/frozen slots keep their token and
+    position, so the cache scatter stays idempotent for them — the same
+    invariant the host loop relies on."""
     step_active = active & jnp.logical_not(done)
     logits, new_caches = model.decode_step(
         params, tokens, caches, pos, active=step_active
@@ -374,25 +368,30 @@ def _block_wave(model, sampler, params, caches, tokens, pos, active, done,
 
 
 def resident_decode_impl(model, residency: StateResidency) -> Callable:
-    """One decode wave over the donated flat state buffer:
+    """One decode wave over the donated flat state buffer, updated in
+    place (each layer writes back only the rows and states it changed):
     ``(params, tokens, buf, pos, active) -> (logits, buf')``."""
 
     def decode_step(params, tokens, buf, pos, active):
-        caches = residency.unpack(buf)
-        logits, new_caches = model.decode_step(
-            params, tokens, caches, pos, active=active
+        logits, state = model.decode_step(
+            params, tokens, residency.slot_buffer(buf), pos, active=active
         )
-        return logits, residency.pack(new_caches, buf)
+        return logits, state.buf
 
     return decode_step
 
 
 def resident_reset_impl(model, residency: StateResidency) -> Callable:
-    """Slot reset over the donated buffer: ``(buf, keep) -> buf'``."""
+    """Slot reset over the donated buffer, ``(buf, keep) -> buf'``: the
+    regions of the slots with ``keep`` False are zeroed (the models'
+    all-zero initial state, as ``model.reset_slots`` gives it) and no
+    other byte is touched."""
+    del model
 
     def reset_slots(buf, keep):
-        caches = residency.unpack(buf)
-        return residency.pack(model.reset_slots(caches, keep), buf)
+        state = residency.slot_buffer(buf)
+        state.zero_slots(keep)
+        return state.buf
 
     return reset_slots
 
@@ -407,13 +406,11 @@ def resident_block_impl(
                      eos):
         def body(carry, _):
             buf, tokens, pos, done, budget, keys = carry
-            caches = residency.unpack(buf)
-            new_caches, (tokens, pos, done, budget, keys), out = (
-                _block_wave(model, sampler, params, caches, tokens,
-                            pos, active, done, budget, keys, eos)
+            state, (tokens, pos, done, budget, keys), out = _block_wave(
+                model, sampler, params, residency.slot_buffer(buf), tokens,
+                pos, active, done, budget, keys, eos,
             )
-            buf = residency.pack(new_caches, buf)
-            return (buf, tokens, pos, done, budget, keys), out
+            return (state.buf, tokens, pos, done, budget, keys), out
 
         carry, (toks, emitted) = jax.lax.scan(
             body, (buf, tokens, pos, done, budget, keys), None,

@@ -37,6 +37,7 @@ from repro.runtime.residency import (
     StateResidency,
     residency_enabled,
 )
+from repro.runtime.sampling import SamplingParams, TokenSampler
 
 ARCHS = ["qwen3-0.6b", "mamba2-2.7b", "zamba2-7b"]
 
@@ -98,7 +99,8 @@ def test_device_arena_store_view_round_trip():
     view = sp.leaf_view_spec()[0]
     n = view.used_nbytes // 4
     value = jnp.arange(n, dtype=jnp.float32)
-    buf = arena.store(buf, view.tensor_id, value)
+    start = view.offset // 4
+    buf = buf.at[start : start + n].set(value)
     got = arena.view(buf, view.tensor_id, (n,), jnp.float32)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(value))
     # and other cells stayed zero
@@ -126,11 +128,6 @@ def test_device_arena_enforces_the_same_bounds_contract_as_arena():
         )
     with pytest.raises(ValueError, match="exceeds planned"):
         host.view(view.tensor_id, (too_big,), np.uint8)
-    with pytest.raises(ValueError, match="exceeds planned"):
-        device.store(
-            device.allocate(), view.tensor_id,
-            jnp.zeros((too_big // 4,), jnp.float32),
-        )
 
 
 # -------------------------------------------------- StateResidency binding
@@ -150,13 +147,13 @@ def test_pack_unpack_round_trips_the_cache_pytree(arch):
         assert p1 == p2
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # pack of nonzero caches round-trips bytes exactly too
+    # packing nonzero caches round-trips bytes exactly too
     nonzero = jax.tree_util.tree_map(
         lambda x: (jnp.arange(x.size, dtype=jnp.float32) % 7 + 1)
         .reshape(x.shape).astype(x.dtype),
         caches,
     )
-    buf2 = jax.jit(res.pack)(nonzero, buf)
+    buf2 = res.init_buffer(nonzero)
     for (_, a), (_, b) in zip(
         jax.tree_util.tree_flatten_with_path(nonzero)[0],
         jax.tree_util.tree_flatten_with_path(res.unpack(buf2))[0],
@@ -252,26 +249,103 @@ def test_v1_bundle_state_materialization_raises_clearly():
 # ------------------------------------------------- backend differential
 
 
-@pytest.mark.parametrize("arch", ARCHS)
-def test_decode_byte_identical_to_xla_allocated_baseline(arch):
+def _slot_bytes(buf, sp, slot) -> bytes:
+    """The bytes of one slot's region of a state buffer."""
+    raw = np.asarray(buf).view(np.uint8)
+    return raw[slot * sp.slot_stride : (slot + 1) * sp.slot_stride].tobytes()
+
+
+def _active_at(case: str, step: int, n_slots: int) -> np.ndarray:
+    """Which slots decode at ``step``: all of them; a mixed mask that
+    changes from step to step; or one slot, as a prompt feed runs."""
+    if case == "mixed":
+        return np.array([(b + step) % 3 != 0 for b in range(n_slots)])
+    if case == "prompt":
+        return np.arange(n_slots) == 1
+    return np.ones(n_slots, bool)
+
+
+DECODE_CASES = [pytest.param(a, "all", id=a) for a in ARCHS] + [
+    pytest.param(a, case, id=f"{a}-{case}")
+    for case in ("mixed", "prompt", "reset", "block")
+    for a in ARCHS
+]
+
+
+def _assert_blocks_identical(arch, cfg, params, sp, res, resident, baseline):
+    """Scan blocks of waves on both backends, from one slot idle and the
+    others stopping at different waves: the same tokens, and after each
+    block the baseline's cache laid out by the plan, byte for byte."""
+    n = sp.n_slots
+    sampler = TokenSampler(SamplingParams(), max_len=sp.max_len)
+    rng = np.random.default_rng(1)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, size=(n, 1)), jnp.int32)
+    pos = jnp.zeros(n, jnp.int32)
+    active = jnp.asarray(np.arange(n) != 1)
+    done = jnp.zeros(n, bool)
+    budget = jnp.asarray(2 + 3 * np.arange(n), jnp.int32)
+    keys = sampler.init_keys(0, n)
+    for blk in range(3):
+        args = (params, tokens, pos, active, done, budget, keys,
+                jnp.int32(-1))
+        r = resident.decode_block(*args, length=3, sampler=sampler)
+        b = baseline.decode_block(*args, length=3, sampler=sampler)
+        np.testing.assert_array_equal(np.asarray(r.wave_tokens),
+                                      np.asarray(b.wave_tokens))
+        np.testing.assert_array_equal(np.asarray(r.emitted),
+                                      np.asarray(b.emitted))
+        assert (np.asarray(resident.buf).tobytes()
+                == np.asarray(res.init_buffer(baseline.caches)).tobytes()), (
+            f"{arch}: state diverged in block {blk}")
+        tokens, pos, done, budget, keys = (r.tokens, r.pos, r.done,
+                                           r.budget, r.keys)
+
+
+@pytest.mark.parametrize("arch,case", DECODE_CASES)
+def test_decode_byte_identical_to_xla_allocated_baseline(arch, case):
     """Acceptance: with residency on, decode logits AND the cache state
     after every step are byte-identical to the XLA-allocated pytree
-    baseline — across attention, SSM, and hybrid shared-attn caches."""
-    cfg, model, params, caches, sp = _setup(arch)
-    res = StateResidency(sp, caches, n_slots=2)
+    baseline — across attention, SSM, and hybrid shared-attn caches.
+    The resident step updates the buffer in place, so beyond that: the
+    regions of slots that did not decode keep every byte (``mixed``,
+    and ``prompt``, one slot active as a prompt feed runs), and a reset
+    between steps zeroes exactly the reset slots' regions (``reset``).
+    The scan block runs the same in-place wave (``block``)."""
+    n = 2 if case == "all" else 3
+    cfg, model, params, caches, sp = _setup(arch, n_slots=n)
+    res = StateResidency(sp, caches, n_slots=n)
     resident = ResidentState(model, res, caches)
     baseline = PytreeState(model, caches)
     assert resident.live_bytes == sp.total_size
+    if case == "block":
+        _assert_blocks_identical(arch, cfg, params, sp, res, resident,
+                                 baseline)
+        return
 
     rng = np.random.default_rng(0)
+    pos = np.zeros(n, np.int32)
     for step in range(5):
+        if case == "reset" and step == 3:
+            keep = np.arange(n) != 1
+            before = np.asarray(resident.buf)
+            resident.reset(keep)
+            baseline.reset(keep)
+            pos[~keep] = 0
+            for b in range(n):
+                got = _slot_bytes(resident.buf, sp, b)
+                want = (_slot_bytes(before, sp, b) if keep[b]
+                        else bytes(len(got)))
+                assert got == want, f"{arch}: reset touched slot {b}"
+        act = _active_at(case, step, n)
         tok = jnp.asarray(
-            rng.integers(0, cfg.vocab, size=(2, 1)), jnp.int32
+            rng.integers(0, cfg.vocab, size=(n, 1)), jnp.int32
         )
-        pos = jnp.full((2,), step, jnp.int32)
-        act = jnp.ones((2,), bool)
-        l_res = resident.decode(params, tok, pos, act)
-        l_base = baseline.decode(params, tok, pos, act)
+        before = np.asarray(resident.buf)
+        l_res = resident.decode(params, tok, jnp.asarray(pos),
+                                jnp.asarray(act))
+        l_base = baseline.decode(params, tok, jnp.asarray(pos),
+                                 jnp.asarray(act))
+        pos += act
         np.testing.assert_array_equal(
             np.asarray(l_res), np.asarray(l_base),
             err_msg=f"{arch}: logits diverged at step {step}",
@@ -280,20 +354,27 @@ def test_decode_byte_identical_to_xla_allocated_baseline(arch):
             jax.tree_util.tree_flatten_with_path(resident.caches)[0],
             jax.tree_util.tree_flatten_with_path(baseline.caches)[0],
         ):
-            np.testing.assert_array_equal(
-                np.asarray(a), np.asarray(b),
-                err_msg=f"{arch}: cache leaf {jax.tree_util.keystr(p)} "
-                        f"diverged at step {step}",
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), (
+                f"{arch}: cache leaf {jax.tree_util.keystr(p)} diverged "
+                f"at step {step}"
             )
+        # every byte of the buffer, padding included, is the baseline's
+        # cache laid out by the plan
+        assert (np.asarray(resident.buf).tobytes()
+                == np.asarray(res.init_buffer(baseline.caches)).tobytes())
+        for b in np.flatnonzero(~act):
+            assert _slot_bytes(resident.buf, sp, b) == \
+                _slot_bytes(before, sp, b), (
+                    f"{arch}: inactive slot {b} changed at step {step}")
     # slot reset round-trips the arena identically too
-    keep = np.array([True, False])
+    keep = np.array([True] + [False] * (n - 1))
     resident.reset(keep)
     baseline.reset(keep)
     for (_, a), (_, b) in zip(
         jax.tree_util.tree_flatten_with_path(resident.caches)[0],
         jax.tree_util.tree_flatten_with_path(baseline.caches)[0],
     ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -10,7 +10,10 @@ chip's 16 GiB. These cases compile, at real widths:
   chunk), which must lower to a Mosaic ``tpu_custom_call``;
 * the decode step of full-width qwen3-0.6b at 8 slots x 4096 tokens on
   each state backend — the cache pytree, the resident flat buffer and
-  the paged pool (1 MiB pages) — each of which must fit one chip.
+  the paged pool (1 MiB pages) — each of which must fit one chip, and
+  full-width mamba2-2.7b's at 16 slots on the resident buffer. The
+  resident step updates the donated buffer in place: it aliases it, and
+  its temporaries stay under a quarter of the state.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -80,9 +83,10 @@ def test_ssd_chunk_lowers_to_mosaic(on_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _decode_program(backend: str, on_chip):
-    """(jitted decode step, avals) of full-width qwen3-0.6b on one chip
-    — the same impl factories and donation the serving backends jit."""
+def _decode_program(backend: str, on_chip, arch: str = "qwen3-0.6b",
+                    slots: int = SLOTS):
+    """(jitted decode step, avals) of full-width ``arch`` on one chip —
+    the same impl factories and donation the serving backends jit."""
     from repro.configs.base import get_config
     from repro.core.unified import (
         detect_state_axes,
@@ -93,23 +97,23 @@ def _decode_program(backend: str, on_chip):
     from repro.models.api import Model
     from repro.runtime import paging, residency
 
-    model = Model.for_config(get_config("qwen3-0.6b"))
-    caches = jax.eval_shape(lambda: model.init_cache(SLOTS, MAX_LEN))
-    records = state_records_from_pytree(caches, n_slots=SLOTS)
+    model = Model.for_config(get_config(arch))
+    caches = jax.eval_shape(lambda: model.init_cache(slots, MAX_LEN))
+    records = state_records_from_pytree(caches, n_slots=slots)
     head = on_chip((
         jax.eval_shape(model.init, jax.random.PRNGKey(0)),
-        jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32),
     ))
     tail = on_chip((
-        jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
-        jax.ShapeDtypeStruct((SLOTS,), jnp.bool_),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_),
     ))
     if backend == "pytree":
         fn = jax.jit(residency.pytree_decode_impl(model))
         return fn, (*head, on_chip(caches), *tail)
     if backend == "resident":
-        plan = plan_state(records, n_slots=SLOTS, max_len=MAX_LEN)
-        res = residency.StateResidency(plan, caches, n_slots=SLOTS)
+        plan = plan_state(records, n_slots=slots, max_len=MAX_LEN)
+        res = residency.StateResidency(plan, caches, n_slots=slots)
         fn = jax.jit(residency.resident_decode_impl(model, res),
                      donate_argnums=residency.DECODE_DONATE)
         return fn, (*head, on_chip(residency.state_buffer_aval(plan)), *tail)
@@ -126,9 +130,14 @@ def _decode_program(backend: str, on_chip):
                 on_chip(pages))
 
 
-@pytest.mark.parametrize("backend", ["pytree", "resident", "paged"])
-def test_full_width_decode_step_fits_one_v5e(backend, on_chip):
-    fn, args = _decode_program(backend, on_chip)
+@pytest.mark.parametrize("backend,arch,slots", [
+    pytest.param("pytree", "qwen3-0.6b", SLOTS, id="pytree"),
+    pytest.param("resident", "qwen3-0.6b", SLOTS, id="resident"),
+    pytest.param("paged", "qwen3-0.6b", SLOTS, id="paged"),
+    pytest.param("resident", "mamba2-2.7b", 16, id="resident-mamba2-2.7b"),
+])
+def test_full_width_decode_step_fits_one_v5e(backend, arch, slots, on_chip):
+    fn, args = _decode_program(backend, on_chip, arch, slots)
     ma = fn.lower(*args).compile().memory_analysis()
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
@@ -139,3 +148,11 @@ def test_full_width_decode_step_fits_one_v5e(backend, on_chip):
     if backend != "pytree":
         # the donated state buffer is reused in place, not copied out
         assert ma.alias_size_in_bytes >= ma.output_size_in_bytes - 2**24
+    if backend == "resident":
+        # each layer reads its state where it lies and writes back only
+        # what it changed: no whole-state temporary
+        state = args[2]
+        state_bytes = state.size * state.dtype.itemsize
+        assert ma.temp_size_in_bytes < state_bytes / 4, (
+            f"{arch}: temp {ma.temp_size_in_bytes} B against a "
+            f"{state_bytes} B state")
