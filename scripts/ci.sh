@@ -4,10 +4,10 @@
 #   ./scripts/ci.sh            # everything
 #   SKIP_BENCH=1 ./scripts/ci.sh   # tests only
 #
-# BENCH_planner.json / BENCH_search.json / BENCH_serve.json /
-# BENCH_throughput.json are the committed perf trajectories — regenerate
-# them here so planner, search, serving, and decode-throughput
-# regressions show up in review diffs.
+# BENCH_planner.json / BENCH_search.json are the committed planner
+# trajectories — regenerate them here so planner and search regressions
+# show up in review diffs. Serving speed is measured on the chip by
+# bench/run.py, not here.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -337,10 +337,4 @@ if [[ -z "${SKIP_BENCH:-}" ]]; then
     # config and strictly smaller on >= 3 (BENCH_search.json is the
     # committed trajectory)
     python benchmarks/order_search_bench.py --quick --out BENCH_search.json
-    # plan-artifact serving smoke: searched <= greedy on every arch,
-    # bundle path does zero trace/plan work, cold-start numbers tracked
-    python benchmarks/serve_bench.py --quick --out BENCH_serve.json
-    # decode-throughput smoke: scan-block vs host loop — greedy byte-
-    # identical, one host sync per block, block tokens/s > host tokens/s
-    python benchmarks/throughput_bench.py --quick --out BENCH_throughput.json
 fi

@@ -27,8 +27,8 @@ them ahead of time from the compiled executable's HLO text:
   wrong length (error).
 
 Programs are lowered shape-level (``jax.eval_shape`` for params; no
-weights are materialized) through the *same* impl factories the serving
-backend jits (``runtime/residency.resident_decode_impl`` & co.), so the
+weights are materialized) through the *same* program factories the
+serving backend jits (``runtime/residency.decode_impl`` & co.), so the
 lint inspects the real decode program, not a stand-in.
 
 :func:`lint_executables` applies the same checks to a v3 bundle's
@@ -295,8 +295,9 @@ def lower_decode_programs(
     """Lower+compile the decode step (and, with ``block``, the scan
     block) for ``arch``'s reduced config, shape-level: params come from
     ``jax.eval_shape`` and the state buffer is an aval — no weights, no
-    cache, no device state is materialized. The impl functions are the
-    same ones ``ResidentState`` jits, with the same donation."""
+    cache, no device state is materialized. The program factories are
+    the ones ``ResidentState`` jits, with the same donation, over the
+    symmetric state."""
     import jax
     import jax.numpy as jnp
 
@@ -307,9 +308,8 @@ def lower_decode_programs(
         BLOCK_DONATE,
         DECODE_DONATE,
         StateResidency,
-        resident_block_impl,
-        resident_decode_impl,
-        state_buffer_aval,
+        block_impl,
+        decode_impl,
     )
     from repro.runtime.sampling import SamplingParams, TokenSampler
 
@@ -324,7 +324,7 @@ def lower_decode_programs(
     resid = StateResidency(sp, caches, n_slots=n_slots)
     params_aval = jax.eval_shape(model.init, jax.random.PRNGKey(0))
 
-    buf_aval = state_buffer_aval(sp)
+    buf_aval = resid.aval
     tok_aval = jax.ShapeDtypeStruct((n_slots, 1), jnp.int32)
     vec_i32 = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
     vec_bool = jax.ShapeDtypeStruct((n_slots,), jnp.bool_)
@@ -335,7 +335,7 @@ def lower_decode_programs(
         DecodeProgram(
             label=f"{arch}:step",
             hlo=jax.jit(
-                resident_decode_impl(model, resid),
+                decode_impl(model, resid),
                 donate_argnums=DECODE_DONATE,
             )
             .lower(params_aval, tok_aval, buf_aval, vec_i32, vec_bool)
@@ -353,7 +353,7 @@ def lower_decode_programs(
             DecodeProgram(
                 label=f"{arch}:block{block}",
                 hlo=jax.jit(
-                    resident_block_impl(model, resid, sampler, block),
+                    block_impl(model, resid, sampler, block),
                     donate_argnums=BLOCK_DONATE,
                 )
                 .lower(params_aval, buf_aval, tok_aval, vec_i32, vec_bool,

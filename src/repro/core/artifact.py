@@ -102,10 +102,11 @@ MANIFEST_FORMAT_VERSION = 1
 # bundles self-invalidate instead of silently serving a plan a current
 # trace would no longer produce. The config-level fingerprint cannot see
 # code changes on its own — this constant is how they re-key; for a
-# trace-backed check at serving time use
-# ``InferenceEngine(verify_bundle=True)``, which compares the stored
-# ``graph_fingerprint`` against a fresh trace. Planner output changes are
-# covered separately by ``plan_io.PLANNER_REVISION``.
+# trace-backed check at serving time serve through
+# ``PlanSession.from_manifest(dir, verify_graph=True)`` (or
+# ``from_bundle``), which compares the stored ``graph_fingerprint``
+# against a fresh trace. Planner output changes are covered separately
+# by ``plan_io.PLANNER_REVISION``.
 PIPELINE_REVISION = 1
 
 
@@ -340,9 +341,9 @@ def expected_executable_entries(
     block_size: int = 1, *, paged: bool = False
 ) -> list[str]:
     """The entry names a complete pack carries for one serving bucket:
-    decode + reset for BOTH state backends (residency is a serving-time
-    knob the compile step cannot predict; paged buckets pair the paged
-    backend with the pytree fallback), plus the full-size scan block on
+    decode + reset for the served addressing and the reference pytree
+    (residency is a serving-time knob the compile step cannot predict;
+    paged buckets pair the paged addressing with the pytree), plus the full-size scan block on
     block-mode buckets (tail blocks have engine-chosen shorter lengths
     and lazy-compile)."""
     backend = "paged" if paged else "resident"

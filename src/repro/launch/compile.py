@@ -23,7 +23,7 @@ The offline half of the compile→artifact→serve pipeline. For one
    coherence — error findings refuse the publish
    (:class:`repro.analysis.LintGateError`);
 4. AOT-compiles the bucket's decode executables (decode step, slot
-   reset, scan block — the exact functions the state backends jit,
+   reset, scan block — the exact programs the state backend jits,
    ``runtime/aot.py``) and serializes them into the bundle, so a served
    node performs **zero XLA compiles** on top of the zero traces / zero
    planner calls. Runs *behind* the lint gate (an unsound plan is

@@ -1,10 +1,8 @@
 """JAX's persistent compilation cache, switched on by the entry points.
 
-``launch/serve.py`` (``run``), ``launch/compile.py`` (``main``),
-``chip_smoke.py`` and ``benchmarks/throughput_bench.py`` call
-:func:`enable_compile_cache` before they compile anything; importing the
-library never does. ``benchmarks/serve_bench.py`` leaves it off: the
-cold compile is what it measures.
+``launch/serve.py`` (``run``), ``launch/compile.py`` (``main``) and
+``chip_smoke.py`` call :func:`enable_compile_cache` before they compile
+anything; importing the library never does.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
 module sets nothing. Otherwise the cache lives at ``<repo>/.jax_cache``

@@ -239,9 +239,9 @@ def run(argv: list[str] | None = None) -> dict:
     pages_total = final_report.state_pages_total
     pages_live = final_report.state_pages_live
     pages_peak = None
-    if getattr(engine.state, "paged", False):
+    if engine.state.pages is not None:
         sp = report.state_plan
-        pages_peak = engine.state.pages_live_peak
+        pages_peak = engine.state.pages.peak
         # page-reuse audit, one level below the slot audit: pool pages
         # are the shared objects; raises if the runtime allocator ever
         # double-assigned a live page

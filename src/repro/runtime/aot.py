@@ -10,9 +10,10 @@ ships the *executables* with the plan, the same ahead-of-time argument
 the paper makes for memory ("the memory manager needs to run only once
 before the first inference", §5) applied to compilation:
 
-* :func:`build_decode_executables` lowers + compiles every decode
-  function a state backend would jit — the module-level impl factories
-  in ``runtime/residency.py``, so the bundled executable IS the program
+* :func:`build_decode_executables` lowers + compiles every program
+  the state backend would jit, for the served addressing and the
+  reference pytree — the module-level program factories in
+  ``runtime/residency.py``, so the bundled executable IS the program
   the engine would have compiled — at the shape level (``jax.eval_shape``
   params, aval state buffer: no weights materialized), serializes each
   one through ``jax.experimental.serialize_executable``, and packs them
@@ -50,30 +51,18 @@ from repro.core.artifact import (
     executable_entry,
     expected_executable_entries,
 )
-from repro.core.unified import PagedStatePlan, StatePlan
+from repro.core.unified import StatePlan
 from repro.launch.jax_cache import persistent_cache_disabled
-from repro.runtime.paging import (
-    PAGED_BLOCK_DONATE,
-    PAGED_DECODE_DONATE,
-    PAGED_RESET_DONATE,
-    PagedStateResidency,
-    paged_block_impl,
-    paged_decode_impl,
-    paged_reset_impl,
-)
 from repro.runtime.residency import (
     BLOCK_DONATE,
     DECODE_DONATE,
     RESET_DONATE,
-    StateResidency,
+    PytreeAddressing,
+    block_impl,
     count_compile,
-    pytree_block_impl,
-    pytree_decode_impl,
-    pytree_reset_impl,
-    resident_block_impl,
-    resident_decode_impl,
-    resident_reset_impl,
-    state_buffer_aval,
+    decode_impl,
+    reset_impl,
+    state_addressing,
 )
 from repro.runtime.sampling import SamplingParams, TokenSampler
 
@@ -121,17 +110,12 @@ def build_decode_executables(
 
     model = Model.for_config(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    caches = jax.eval_shape(lambda: model.init_cache(n_slots, max_len))
-    paged = isinstance(state_plan, PagedStatePlan)
-    if paged:
-        residency = PagedStateResidency(state_plan, caches, n_slots=n_slots)
-        pages = jax.ShapeDtypeStruct(
-            (n_slots, state_plan.pages_per_slot), jnp.int32
-        )
-    else:
-        residency = StateResidency(state_plan, caches, n_slots=n_slots)
-        pages = None
-    buf = state_buffer_aval(state_plan)
+    reference = PytreeAddressing(model, n_slots=n_slots, max_len=max_len)
+    served = state_addressing(state_plan, reference.aval, n_slots=n_slots)
+    sampler = TokenSampler(
+        SamplingParams(greedy=greedy, temperature=temperature, top_k=top_k),
+        max_len=max_len,
+    )
 
     tok = jax.ShapeDtypeStruct((n_slots, 1), jnp.int32)
     vec_i32 = jax.ShapeDtypeStruct((n_slots,), jnp.int32)
@@ -147,7 +131,7 @@ def build_decode_executables(
     # these compiles skip the cache
     skip_cache = jax.default_backend() == "cpu"
 
-    def _compile(name, fn, avals, donate=()):
+    def _compile(name, fn, avals, donate):
         with (persistent_cache_disabled() if skip_cache
               else contextlib.nullcontext()):
             compiled = (
@@ -157,71 +141,29 @@ def build_decode_executables(
         entries[name] = executable_entry(serialize_compiled(compiled))
         return compiled
 
-    pytree_decode = _compile(
-        "pytree_decode",
-        pytree_decode_impl(model),
-        (params, tok, caches, vec_i32, vec_bool),
-    )
-    _compile(
-        "pytree_reset", pytree_reset_impl(model), (caches, vec_bool)
-    )
-    if paged:
+    for addressing in (reference, served):
+        prefix, state = addressing.prefix, addressing.aval
+        extra = addressing.arg_avals()
+        decode = _compile(
+            f"{prefix}_decode", decode_impl(model, addressing),
+            (params, tok, state, vec_i32, vec_bool, *extra),
+            addressing.donated(DECODE_DONATE),
+        )
+        if addressing is reference:
+            xla_temp = decode.memory_analysis().temp_size_in_bytes or None
         _compile(
-            "paged_decode",
-            paged_decode_impl(model, residency),
-            (params, tok, buf, vec_i32, vec_bool, pages),
-            donate=PAGED_DECODE_DONATE,
+            f"{prefix}_reset", reset_impl(model, addressing),
+            (state, vec_bool, *extra), addressing.donated(RESET_DONATE),
         )
-        _compile(
-            "paged_reset",
-            paged_reset_impl(model, residency),
-            (buf, vec_bool, pages),
-            donate=PAGED_RESET_DONATE,
-        )
-    else:
-        _compile(
-            "resident_decode",
-            resident_decode_impl(model, residency),
-            (params, tok, buf, vec_i32, vec_bool),
-            donate=DECODE_DONATE,
-        )
-        _compile(
-            "resident_reset",
-            resident_reset_impl(model, residency),
-            (buf, vec_bool),
-            donate=RESET_DONATE,
-        )
-    if block_size > 1:
-        sampler = TokenSampler(
-            SamplingParams(
-                greedy=greedy, temperature=temperature, top_k=top_k
-            ),
-            max_len=max_len,
-        )
-        if paged:
+        if block_size > 1:
             _compile(
-                block_entry_name("paged", block_size),
-                paged_block_impl(model, residency, sampler, block_size),
-                (params, buf, tok, vec_i32, vec_bool, vec_bool, vec_i32,
-                 keys, eos, pages),
-                donate=PAGED_BLOCK_DONATE,
+                block_entry_name(prefix, block_size),
+                block_impl(model, addressing, sampler, block_size),
+                (params, state, tok, vec_i32, vec_bool, vec_bool, vec_i32,
+                 keys, eos, *extra),
+                addressing.donated(BLOCK_DONATE),
             )
-        else:
-            _compile(
-                block_entry_name("resident", block_size),
-                resident_block_impl(model, residency, sampler, block_size),
-                (params, buf, tok, vec_i32, vec_bool, vec_bool, vec_i32,
-                 keys, eos),
-                donate=BLOCK_DONATE,
-            )
-        _compile(
-            block_entry_name("pytree", block_size),
-            pytree_block_impl(model, sampler, block_size),
-            (params, caches, tok, vec_i32, vec_bool, vec_bool, vec_i32,
-             keys, eos),
-        )
 
-    xla_temp = pytree_decode.memory_analysis().temp_size_in_bytes or None
     pack = ExecutablePack(
         platform=jax.default_backend(),
         jax_version=jax.__version__,

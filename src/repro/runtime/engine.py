@@ -15,15 +15,15 @@ feature. At engine construction we:
    does not match — the engine traces the decode step
    (``trace/jaxpr_liveness``) and plans it (paper §5), recording a
    one-line warning in the report;
-2. materialize the activation arena straight from the plan's offsets
-   (``engine.activation_arena`` — allocate once, serve forever) and
-   MATERIALIZE the cross-step state from the plan too: with state
-   residency on (default; ``REPRO_STATE_RESIDENCY=off`` to disable) the
-   per-slot KV caches and decode buffers live as views over ONE flat
-   device buffer of exactly ``StatePlan.total_size`` bytes
-   (``runtime/residency.py``), donate-threaded through the decode jit so
-   XLA reuses the same allocation every wave — the planned layout is the
-   live layout, not an accounting overlay;
+2. lay out the activation arena straight from the plan's offsets
+   (``engine.activation_layout``) and MATERIALIZE the cross-step state
+   from the plan: with state residency on (default;
+   ``REPRO_STATE_RESIDENCY=off`` to disable) the per-slot KV caches and
+   decode buffers live as views over ONE flat device buffer of exactly
+   ``StatePlan.total_size`` bytes (``runtime/residency.py``),
+   donate-threaded through the decode jit so XLA reuses the same
+   allocation every wave — the planned layout is the live layout, not
+   an accounting overlay;
 3. lay out the CROSS-STEP state (per-slot KV caches + decode buffers) as
    a Shared-Objects instance where ``op index == decode wave`` — slots
    are the shared objects, requests are the tensors (paper §4 applied
@@ -65,7 +65,6 @@ import dataclasses
 import os
 import time
 import warnings
-from pathlib import Path
 from typing import Any
 
 import jax
@@ -74,17 +73,11 @@ import numpy as np
 
 from repro.analysis import counters
 from repro.configs.base import ArchConfig
-from repro.core.artifact import (
-    PlanBundle,
-    decode_fingerprint,
-    serve_fingerprint,
-)
-from repro.core.graph import Graph
+from repro.core.artifact import decode_fingerprint, serve_fingerprint
 from repro.core.planner import MemoryPlan, plan_graph
 from repro.core.unified import (
     PagedStatePlan,
     PlanSession,
-    PlanSpec,
     StatePlan,
     UnifiedPlan,
     detect_state_axes,
@@ -93,18 +86,13 @@ from repro.core.unified import (
     state_records_from_pytree,
 )
 from repro.models.api import Model
-from repro.runtime.arena import Arena
-from repro.runtime.paging import (
-    PagedOutOfPagesError,
-    PagedResidentState,
-    PagedStateResidency,
-)
+from repro.runtime.paging import PagedOutOfPagesError
 from repro.runtime.residency import (
     BlockOut,
-    PytreeState,
+    PytreeAddressing,
     ResidentState,
-    StateResidency,
     residency_enabled,
+    state_addressing,
 )
 from repro.runtime.sampling import (
     SamplingParams,
@@ -286,58 +274,6 @@ class MemoryReport:
         return "\n".join(lines)
 
 
-def _session_from_legacy_kwargs(
-    session: PlanSession | None,
-    *,
-    plan_strategy: str | None,
-    activation_graph: Graph | None,
-    plan_bundle: PlanBundle | str | Path | None,
-    verify_bundle: bool | None,
-) -> PlanSession | None:
-    """Deprecated-kwarg shim: the pre-unified plan-source kwargs map onto
-    a PlanSession. ``plan_bundle`` keeps its historical exact-bucket
-    semantics (``nearest=False``); new callers get auto-selection through
-    ``PlanSession.from_manifest``."""
-    # explicitly-passed OLD DEFAULTS are semantic no-ops, not deprecated
-    # usage — callers migrating incrementally must be able to combine
-    # them with session= (the downstream spec/verify defaults reproduce
-    # them exactly)
-    if plan_strategy == "auto":
-        plan_strategy = None
-    if verify_bundle is False:
-        verify_bundle = None
-    legacy = {
-        "plan_strategy": plan_strategy,
-        "activation_graph": activation_graph,
-        "plan_bundle": plan_bundle,
-        "verify_bundle": verify_bundle,
-    }
-    used = [k for k, v in legacy.items() if v is not None]
-    if not used:
-        return session
-    if session is not None:
-        raise ValueError(
-            f"pass either session= or the deprecated {used} kwargs, not both"
-        )
-    warnings.warn(
-        f"InferenceEngine({', '.join(used)}=...) is deprecated; pass "
-        f"session=PlanSession.from_manifest(dir) / .from_bundle(b) / "
-        f".from_spec(PlanSpec(...)) instead",
-        DeprecationWarning,
-        stacklevel=3,
-    )
-    verify = bool(verify_bundle)
-    if plan_bundle is not None:
-        if not isinstance(plan_bundle, PlanBundle) and Path(plan_bundle).is_dir():
-            return PlanSession.from_manifest(
-                plan_bundle, nearest=False, verify_graph=verify
-            )
-        return PlanSession.from_bundle(plan_bundle, verify_graph=verify)
-    return PlanSession.from_spec(
-        PlanSpec(graph=activation_graph, strategy=plan_strategy or "auto")
-    )
-
-
 class InferenceEngine:
     def __init__(
         self,
@@ -371,21 +307,9 @@ class InferenceEngine:
         # at publish time, and the tracer/planner are differentially
         # tested, so the per-process re-proof is opt-in paranoia)
         startup_lint: bool | None = None,
-        # deprecated plan-source kwargs — use session=PlanSession...
-        plan_strategy: str | None = None,
-        activation_graph: Graph | None = None,
-        plan_bundle: PlanBundle | str | Path | None = None,
-        verify_bundle: bool | None = None,
     ):
         if cfg.family == "audio":
             raise NotImplementedError("engine drives decoder-only archs")
-        session = _session_from_legacy_kwargs(
-            session,
-            plan_strategy=plan_strategy,
-            activation_graph=activation_graph,
-            plan_bundle=plan_bundle,
-            verify_bundle=verify_bundle,
-        )
         self.cfg = cfg
         self.model = Model.for_config(cfg)
         self.params = params
@@ -580,58 +504,42 @@ class InferenceEngine:
             if aot_warning:
                 warnings.warn(aot_warning, RuntimeWarning, stacklevel=2)
         # allocate-once deployment: BOTH layouts come from the one unified
-        # plan; the activation arena is materialized (the decode step's
-        # scratch bytes) and — with residency on — so is the cross-step
-        # state: ONE flat device buffer of exactly StatePlan.total_size
-        # bytes, donate-threaded through the decode jit. With residency
-        # off the state layout degrades to the PR 4 accounting overlay
-        # over an XLA-allocated cache pytree.
-        act_layout, self.state_layout = self.unified_plan.arena_layouts()
-        self.activation_arena = Arena(act_layout)
-        self.residency: StateResidency | None = None
-        paged_plan = isinstance(state_plan, PagedStatePlan)
-        if not residency_enabled(state_residency):
-            if paged_plan:
-                # the pytree backend has no page indirection, so serving
-                # it would silently drop the paging that was asked for
-                raise ValueError(
-                    "paged state requires state residency; serve with "
-                    "residency on, or drop page_size"
-                )
-            self.state = PytreeState(
-                self.model,
-                self.model.init_cache(n_slots, self.max_len),
-                executables=aot_execs,
-            )
-        elif paged_plan:
-            # page-table addressing over the physical pool buffer; page
-            # allocation bookkeeping lives in the backend, driven by
-            # _admit / retirement below
-            self.residency = PagedStateResidency(
+        # plan. The cross-step state is materialized by the backend: with
+        # residency on, ONE device buffer laid out by the plan (flat, or
+        # the page pool under a paged plan), donate-threaded through every
+        # program; off, the XLA-allocated cache pytree (the reference),
+        # which has no page indirection, so serving it would silently
+        # drop the paging that was asked for.
+        self.activation_layout, self.state_layout = (
+            self.unified_plan.arena_layouts()
+        )
+        if residency_enabled(state_residency):
+            addressing = state_addressing(
                 state_plan, cache_template, n_slots=n_slots,
                 layout=self.state_layout,
             )
-            self.state = PagedResidentState(
-                self.model, self.residency, executables=aot_execs
+        elif isinstance(state_plan, PagedStatePlan):
+            raise ValueError(
+                "paged state requires state residency; serve with "
+                "residency on, or drop page_size"
             )
         else:
-            self.residency = StateResidency(
-                state_plan, cache_template, n_slots=n_slots,
-                layout=self.state_layout,
+            addressing = PytreeAddressing(
+                self.model, n_slots=n_slots, max_len=self.max_len
             )
-            # zero-init straight into the flat buffer (init_cache's
-            # contract is all-zero state): on this path the engine NEVER
-            # materializes a cache pytree, so cold start holds exactly
-            # one state allocation, not pytree + arena
-            self.state = ResidentState(
-                self.model, self.residency, executables=aot_execs
-            )
-        paged_backend = bool(getattr(self.state, "paged", False))
+        # on the resident paths the state is zero-initialized straight
+        # into the buffer (init_cache's contract is all-zero state): the
+        # engine NEVER materializes a cache pytree there, so cold start
+        # holds exactly one state allocation, not pytree + arena
+        self.state = ResidentState(
+            self.model, addressing, executables=aot_execs
+        )
+        pages = self.state.pages
         self._memory_report = MemoryReport(
             activation_plan=plan,
             xla_temp_bytes=xla_temp,
             cache_bytes_per_slot=(
-                0 if paged_backend else state_plan.bytes_per_slot
+                state_plan.bytes_per_slot if pages is None else 0
             ),
             n_slots=n_slots,
             plan_cache_hit=plan.cache_hit,
@@ -642,13 +550,9 @@ class InferenceEngine:
             state_live_bytes=self.state.live_bytes,
             aot_executables=sorted(aot_execs),
             aot_warning=aot_warning,
-            state_pages_total=(
-                self.state.pages_total if paged_backend else None
-            ),
-            state_pages_live=0 if paged_backend else None,
-            state_page_size=(
-                state_plan.page_size if paged_backend else None
-            ),
+            state_pages_total=None if pages is None else pages.total,
+            state_pages_live=None if pages is None else pages.live,
+            state_page_size=None if pages is None else pages.plan.page_size,
         )
 
         # serving state — per-slot positions (continuous batching: every
@@ -695,14 +599,14 @@ class InferenceEngine:
         ``state_live_bytes`` track the pool — so the report tells the
         truth mid-serve, not just at construction."""
         rep = self._memory_report
-        if not getattr(self.state, "paged", False):
+        if self.state.pages is None:
             return rep
         return dataclasses.replace(
             rep,
             cache_bytes_per_slot=(
                 self.state.live_bytes // max(len(self._active), 1)
             ),
-            state_pages_live=self.state.pages_live,
+            state_pages_live=self.state.pages.live,
             state_live_bytes=self.state.live_bytes,
         )
 
@@ -710,9 +614,10 @@ class InferenceEngine:
     def page_log(self) -> list[tuple[int, int, int, int]]:
         """Page occupancy intervals ``(page, admitted_wave,
         finished_wave, request_id)`` — the page-granular twin of
-        ``slot_log`` (empty on non-paged backends), audited by
+        ``slot_log`` (empty where the state is not paged), audited by
         ``shared_objects.from_page_log``."""
-        return list(getattr(self.state, "page_log", []))
+        pages = self.state.pages
+        return [] if pages is None else list(pages.log)
 
     def _step_tokens(self, tokens: np.ndarray, pos: np.ndarray,
                      active: np.ndarray):
@@ -736,34 +641,30 @@ class InferenceEngine:
         """Give queued requests the free slots, in FIFO order; returns
         the ``(slot, request)`` pairs admitted."""
         free = [s for s in range(self.n_slots) if s not in self._active]
-        paged = getattr(self.state, "paged", False)
         taken: list[tuple[int, Request]] = []
         while free and self._queue:
-            if paged:
-                # allocate-before-admit: map the pages the head request
-                # needs (its cache never grows past prompt + budget,
-                # capped by the bucket length) BEFORE touching any slot
-                # state. A refused allocation mutates nothing: with
-                # active slots we stop admitting and retry after the
-                # next retirement returns pages (FIFO head-of-line, so
-                # the admission schedule stays deterministic); with NO
-                # active slots the whole pool is free, so the request
-                # can never fit this bucket and the error propagates.
-                req = self._queue[0]
-                needed = min(
-                    len(req.prompt) + req.max_new_tokens, self.max_len
+            # admit before touching any slot state: the paged state maps
+            # the pages the head request needs (its cache never grows
+            # past prompt + budget, capped by the bucket length). A
+            # refused allocation mutates nothing: with active slots we
+            # stop admitting and retry after the next retirement returns
+            # pages (FIFO head-of-line, so the admission schedule stays
+            # deterministic); with NO active slots the whole pool is
+            # free, so the request can never fit this bucket and the
+            # error propagates.
+            req = self._queue[0]
+            try:
+                self.state.admit(
+                    free[0],
+                    min(len(req.prompt) + req.max_new_tokens, self.max_len),
+                    rid=req.request_id, wave=self._wave,
                 )
-                try:
-                    self.state.allocate_slot(
-                        free[0], needed, rid=req.request_id,
-                        wave=self._wave,
-                    )
-                except PagedOutOfPagesError:
-                    if self._active:
-                        break
-                    raise
+            except PagedOutOfPagesError:
+                if self._active:
+                    break
+                raise
             slot = free.pop(0)
-            req = self._queue.pop(0)
+            self._queue.pop(0)
             req.admitted_wave = self._wave
             req.admitted_s = time.perf_counter()
             self._active[slot] = req
@@ -868,8 +769,7 @@ class InferenceEngine:
                     )
                     finished.append(req)
                     del self._active[slot]
-                    if getattr(self.state, "paged", False):
-                        self.state.free_slot(slot, self._wave)
+                    self.state.retire(slot, self._wave)
         self._wave += 1
         return finished
 
@@ -988,8 +888,7 @@ class InferenceEngine:
                     )
                     finished.append(req)
                     del self._active[slot]
-                    if getattr(self.state, "paged", False):
-                        self.state.free_slot(slot, wave)
+                    self.state.retire(slot, wave)
         self._wave = inflight.base_wave + inflight.length
         return finished
 
@@ -1066,11 +965,3 @@ class InferenceEngine:
                 raise WavesExhaustedError(msg, self.unfinished_requests())
             warnings.warn(msg, RuntimeWarning, stacklevel=2)
         return done
-
-
-def _softmax(x: np.ndarray) -> np.ndarray:
-    """Backwards-compatible alias of :func:`repro.runtime.sampling.softmax`
-    (float64 + explicit renormalization — see the bugfix note there)."""
-    from repro.runtime.sampling import softmax
-
-    return softmax(x)

@@ -1,32 +1,34 @@
-"""Paged state backend: the residency buffer addressed through per-slot
-page tables (ROADMAP open item 2 — §4 shared objects at page granularity).
+"""Paged state: the residency buffer addressed through per-slot page
+tables (ROADMAP open item 2 — §4 shared objects at page granularity).
 
-The symmetric :class:`~repro.runtime.residency.ResidentState` gives every
-slot a full ``max_len`` region, so a 64-token request in a 4096-token
-bucket strands ~98% of its planned state bytes. This module keeps the
-*logical* layout identical — the same
+The symmetric :class:`~repro.runtime.residency.StateResidency` gives
+every slot a full ``max_len`` region, so a 64-token request in a
+4096-token bucket strands ~98% of its planned state bytes. This module
+keeps the *logical* layout identical — the same
 :class:`~repro.core.unified.StatePlan` leaves, offsets and strides the
 whole codebase reasons about — but backs it with a pool of fixed-size
 physical pages (:class:`~repro.core.unified.PagedStatePlan`):
 
-* :class:`PagedStateResidency` re-binds the cache pytree to the plan
-  through a page-table indirection: ``unpack`` gathers each slot's
-  logical region from its table row (``jnp.take`` over the buffer's
-  page rows), ``pack`` scatters it back — one gather + one scatter per
-  decode wave, all shapes static, so the decode jit stays a fixed
-  program and the table is plain int32 *data* (no retrace, no
-  recompile when the mapping changes);
+* :class:`PagedStateResidency` is the paged addressing of the one
+  serving backend (:class:`~repro.runtime.residency.ResidentState`): a
+  program enters the state by gathering each slot's logical region from
+  its table row (``unpack``, ``jnp.take`` over the buffer's page rows)
+  and leaves it by scattering it back (``pack``) — one gather + one
+  scatter per program, all shapes static, so the program stays fixed
+  and the table, its last argument, is plain int32 *data* (no retrace,
+  no recompile when the mapping changes);
 * physical page 0 is the reserved all-zero **null page**: unmapped
   logical pages read as zeros through it, and every scatter row aimed
   at it provably carries zeros (unmapped bytes are zeros on the way in
   and the decode masks its cache updates by ``active``), so duplicate
   scatter indices are benign;
-* :class:`PagedResidentState` adds the serving-time bookkeeping:
-  allocate-on-admit (:meth:`~PagedResidentState.allocate_slot` maps the
-  pages a request's ``needed_len`` intersects, refusing with
+* :class:`PagePool`, owned by the addressing, is the serving-time
+  bookkeeping the backend's ``admit``/``retire`` hooks drive:
+  allocate-on-admit (:meth:`~PagePool.allocate_slot` maps the pages a
+  request's ``needed_len`` intersects, refusing with
   :class:`PagedOutOfPagesError` when the pool cannot cover it) and
-  free-on-retire (:meth:`~PagedResidentState.free_slot`), with a page
-  log mirroring the engine's slot log for the §4-style audit
+  free-on-retire (:meth:`~PagePool.free_slot`), with a page log
+  mirroring the engine's slot log for the §4-style audit
   (``shared_objects.from_page_log``).
 
 **Byte-identity discipline.** Retirement frees a slot's pages but does
@@ -45,30 +47,14 @@ wipe.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.artifact import block_entry_name
 from repro.core.unified import PagedStatePlan
-from repro.runtime.residency import (
-    BlockOut,
-    StateResidency,
-    _block_wave,
-    _LazyJit,
-    decode_and_wait,
-    scoped_call,
-    state_buffer_aval,
-)
-
-# Donated argument positions for the paged jits (the page table rides
-# LAST and is never donated — it is a tiny int32 input the host mutates
-# between dispatches).
-PAGED_DECODE_DONATE = (2,)  # (params, tokens, BUF, pos, active, pages)
-PAGED_RESET_DONATE = (0,)  # (BUF, keep, pages)
-PAGED_BLOCK_DONATE = (1,)  # (params, BUF, tokens, pos, active, ..., pages)
+from repro.runtime.residency import Addressing, StateResidency, scoped_call
 
 
 class PagedOutOfPagesError(RuntimeError):
@@ -96,16 +82,131 @@ class PagedOutOfPagesError(RuntimeError):
         )
 
 
+class PagePool:
+    """The physical pages of a paged plan and which slot holds each, for
+    one engine: the host-authoritative page table (mirrored to the
+    device as ``table_dev`` only when a mapping changes, at admission),
+    the free list, each mapped page's owner, and the page log."""
+
+    def __init__(self, plan: PagedStatePlan, n_slots: int):
+        self.plan = plan
+        # 0 = the null page
+        self.table = np.zeros((n_slots, plan.pages_per_slot), np.int32)
+        self.table_dev = jnp.array(self.table)
+        # free pool as physical page indices (ascending — deterministic
+        # assignment order), page -> (slot, logical_idx) for EVERY
+        # mapped page (live or stale), and the live set: pages held by
+        # currently-active slots
+        self._free: list[int] = sorted(
+            o // plan.page_size for o in plan.page_offsets
+        )
+        self._owner: dict[int, tuple[int, int]] = {}
+        self._live: set[int] = set()
+        self._page_admit: dict[int, int] = {}  # page -> admitted wave
+        self._slot_rid: dict[int, int] = {}
+        # page occupancy intervals, the page-granular twin of the
+        # engine's slot_log: (page, admitted_wave, finished_wave, rid)
+        self.log: list[tuple[int, int, int, int]] = []
+        self.peak = 0  # most pages live at once
+
+    @property
+    def total(self) -> int:
+        return self.plan.n_pages_pool
+
+    @property
+    def live(self) -> int:
+        return len(self._live)
+
+    def slot_pages(self, slot: int) -> list[int]:
+        """The physical pages ``slot`` holds LIVE (mapped and counted
+        against the pool; stale mappings of a retired slot excluded)."""
+        return sorted(
+            int(p) for p in self.table[slot] if p and int(p) in self._live
+        )
+
+    def allocate_slot(
+        self, slot: int, needed_len: int, *, rid: int, wave: int
+    ) -> int:
+        """Map the pages ``slot`` needs to serve a request whose cache
+        never grows past ``needed_len`` rows. Returns the number of
+        pages now live for the slot; raises :class:`PagedOutOfPagesError`
+        (mutating NOTHING) when the free pool cannot cover the need.
+
+        Assignment order is the byte-identity ladder from the module
+        docstring: the slot's own stale pages first, never-mapped free
+        pages next, stolen stale pages of other retired slots last —
+        each group in ascending physical order, so runs are
+        deterministic."""
+        need = self.plan.pages_needed(needed_len)
+        free_set = set(self._free)
+        assigned: dict[int, int] = {}
+        for j in need:
+            p = int(self.table[slot, j])
+            if p and p in free_set:  # (1) stale-self: still mapped here
+                assigned[j] = p
+                free_set.discard(p)
+        remaining = [j for j in need if j not in assigned]
+        avail = sorted(free_set)
+        pool = [p for p in avail if p not in self._owner] + [
+            p for p in avail if p in self._owner
+        ]
+        if len(remaining) > len(pool):
+            raise PagedOutOfPagesError(
+                pages_needed=len(need),
+                pages_free=len(self._free),
+                pages_live=len(self._live),
+                pages_total=self.plan.n_pages_pool,
+            )
+        dirty = False
+        for j, p in zip(remaining, pool):
+            old = self._owner.get(p)
+            if old is not None:  # (3) steal: clear the stale owner's map
+                self.table[old[0], old[1]] = 0
+            self.table[slot, j] = p
+            self._owner[p] = (slot, j)
+            assigned[j] = p
+            dirty = True
+        taken = set(assigned.values())
+        self._free = sorted(set(self._free) - taken)
+        for p in taken:
+            self._live.add(p)
+            self._page_admit[p] = wave
+        self._slot_rid[slot] = rid
+        self.peak = max(self.peak, len(self._live))
+        if dirty:
+            self.table_dev = jnp.array(self.table)
+        return len(taken)
+
+    def free_slot(self, slot: int, wave: int) -> list[int]:
+        """Return a retired slot's live pages to the free pool and log
+        their occupancy intervals. The table row is NOT cleared (lazy
+        invalidation — see module docstring), so the device table needs
+        no refresh and the retired slot's stale bytes stay readable,
+        exactly like the symmetric baseline's."""
+        released = self.slot_pages(slot)
+        rid = self._slot_rid.get(slot, -1)
+        for p in released:
+            self._live.discard(p)
+            self.log.append((p, self._page_admit.pop(p), wave, rid))
+        self._free = sorted(set(self._free) | set(released))
+        return released
+
+
 class PagedStateResidency(StateResidency):
     """The :class:`~repro.runtime.residency.StateResidency` binding with
     page-table addressing: the buffer is ``n_pages_total`` rows of one
     physical page each (null page at row 0), and every (slot, leaf) cell
     is reached by gathering the slot's table row instead of a static
-    ``slot * slot_stride`` base.
+    ``slot * slot_stride`` base. A program enters the state as the
+    gathered cache pytree, which the model takes as the reference does,
+    and leaves it by scattering that back; the page table of its
+    :class:`PagePool` is the program's last argument.
 
     Binding validation is inherited wholesale — the logical layout IS
     the symmetric plan's, so path/dtype/per-slot-byte checks are
     unchanged."""
+
+    prefix = "paged"
 
     def __init__(
         self,
@@ -145,10 +246,7 @@ class PagedStateResidency(StateResidency):
                 f"of {self.dtype.name} elements"
             )
         self.page_elems = state_plan.page_size // self.dtype.itemsize
-
-    @property
-    def phys_total_size(self) -> int:
-        return self.paged_plan.phys_total_size
+        self.pool = PagePool(state_plan, n_slots)
 
     def init_buffer(self, caches: Any = None):
         """A fresh physical buffer: the null page + the whole pool,
@@ -163,8 +261,7 @@ class PagedStateResidency(StateResidency):
                 "paged residency initializes zero state only (allocate "
                 "pages, then pack through the table)"
             )
-        aval = state_buffer_aval(self.paged_plan)
-        return jnp.zeros(aval.shape, aval.dtype)
+        return jnp.zeros(self.aval.shape, self.aval.dtype)
 
     def _leaf_span(self, views) -> tuple[int, int]:
         """(element offset, element count) of a leaf inside a slot's
@@ -238,267 +335,24 @@ class PagedStateResidency(StateResidency):
             )
         return buf.at[pages.reshape(-1)].set(jnp.concatenate(slot_rows))
 
+    # a program gathers the cache pytree through the tables on entry and
+    # scatters it back on leaving; the model takes the pytree, as it
+    # takes the reference's
+    enter, leave = unpack, pack
+    view, unview, reset = Addressing.view, Addressing.unview, Addressing.reset
 
-# ------------------------------------------------- jitted decode functions
-#
-# Module-level factories, same discipline as runtime/residency.py: the
-# serving backend, the AOT compiler (runtime/aot.py) and the static
-# decode lint all lower THESE functions. The page table is the LAST
-# positional argument of every one.
+    def args(self) -> tuple:
+        return (self.pool.table_dev,)
 
+    def live_bytes(self, value) -> int:
+        """Pool bytes holding live state — the paged win the report
+        tracks: ``pool.live * page_size``, against the symmetric state's
+        constant ``StatePlan.total_size``."""
+        return self.pool.live * self.paged_plan.page_size
 
-def paged_decode_impl(model, residency: PagedStateResidency) -> Callable:
-    """One decode wave through the page tables:
-    ``(params, tokens, buf, pos, active, pages) -> (logits, buf')``."""
+    def admit(self, slot: int, needed_len: int, *, rid: int,
+              wave: int) -> None:
+        self.pool.allocate_slot(slot, needed_len, rid=rid, wave=wave)
 
-    def decode_step(params, tokens, buf, pos, active, pages):
-        caches = residency.unpack(buf, pages)
-        logits, new_caches = model.decode_step(
-            params, tokens, caches, pos, active=active
-        )
-        return logits, residency.pack(new_caches, buf, pages)
-
-    return decode_step
-
-
-def paged_reset_impl(model, residency: PagedStateResidency) -> Callable:
-    """Slot reset through the page tables:
-    ``(buf, keep, pages) -> buf'`` — zeroes every page the dropped
-    slots still map (stale mappings included: the symmetric baseline
-    wipes the whole slot region at admit, and so does this)."""
-
-    def reset_slots(buf, keep, pages):
-        caches = residency.unpack(buf, pages)
-        return residency.pack(model.reset_slots(caches, keep), buf, pages)
-
-    return reset_slots
-
-
-def paged_block_impl(
-    model, residency: PagedStateResidency, sampler, length: int
-) -> Callable:
-    """``length`` decode waves in one ``lax.scan``: gather the cache
-    pytree through the tables ONCE, scan the waves over the pytree
-    carry, scatter back ONCE. ``pack``/``unpack`` are exact inverses on
-    values, so this is wave-for-wave identical to the symmetric block's
-    per-wave pack/unpack — with a 1/length page-indirection cost."""
-
-    def decode_block(params, buf, tokens, pos, active, done, budget, keys,
-                     eos, pages):
-        caches0 = residency.unpack(buf, pages)
-
-        def body(carry, _):
-            caches, tokens, pos, done, budget, keys = carry
-            caches, (tokens, pos, done, budget, keys), out = (
-                _block_wave(model, sampler, params, caches, tokens,
-                            pos, active, done, budget, keys, eos)
-            )
-            return (caches, tokens, pos, done, budget, keys), out
-
-        carry, (toks, emitted) = jax.lax.scan(
-            body, (caches0, tokens, pos, done, budget, keys), None,
-            length=length,
-        )
-        caches, tokens, pos, done, budget, keys = carry
-        buf = residency.pack(caches, buf, pages)
-        return (buf, tokens, pos, done, budget, keys), toks, emitted
-
-    return decode_block
-
-
-class PagedResidentState:
-    """Serving backend: the donated flat buffer addressed through
-    per-slot page tables, with allocate-on-admit / free-on-retire page
-    bookkeeping.
-
-    Same decode/reset/decode_block interface as
-    :class:`~repro.runtime.residency.ResidentState` (the engine is
-    oblivious to the indirection), plus the page lifecycle the engine's
-    admission path drives: :meth:`allocate_slot` before a slot is
-    reset/prefilled, :meth:`free_slot` when it retires."""
-
-    residency = True
-    paged = True
-
-    def __init__(
-        self,
-        model,
-        residency: PagedStateResidency,
-        *,
-        executables: "dict[str, Any] | None" = None,
-    ):
-        self.model = model
-        self._residency = residency
-        plan = residency.paged_plan
-        self.plan = plan
-        self.buf = residency.init_buffer()
-        # host-authoritative page table, mirrored to device only when a
-        # mapping actually changes (admission); 0 = null page
-        self._table = np.zeros(
-            (residency.n_slots, plan.pages_per_slot), np.int32
-        )
-        self._table_dev = jnp.array(self._table)
-        # free pool as physical page indices (ascending — deterministic
-        # assignment order), page -> (slot, logical_idx) for EVERY
-        # mapped page (live or stale), and the live set: pages held by
-        # currently-active slots
-        self._free: list[int] = sorted(
-            o // plan.page_size for o in plan.page_offsets
-        )
-        self._owner: dict[int, tuple[int, int]] = {}
-        self._live: set[int] = set()
-        self._page_admit: dict[int, int] = {}  # page -> admitted wave
-        self._slot_rid: dict[int, int] = {}
-        # page occupancy intervals, the page-granular twin of the
-        # engine's slot_log: (page, admitted_wave, finished_wave, rid)
-        self.page_log: list[tuple[int, int, int, int]] = []
-        self.pages_live_peak = 0
-        self._execs = executables or {}
-        self._decode = self._execs.get("paged_decode") or _LazyJit(
-            paged_decode_impl(model, residency),
-            donate_argnums=PAGED_DECODE_DONATE,
-        )
-        self._reset = self._execs.get("paged_reset") or _LazyJit(
-            paged_reset_impl(model, residency),
-            donate_argnums=PAGED_RESET_DONATE,
-        )
-        self._block_jits: dict[int, Any] = {}  # scan length -> callable
-
-    # ------------------------------------------------- page lifecycle
-    @property
-    def pages_total(self) -> int:
-        return self.plan.n_pages_pool
-
-    @property
-    def pages_live(self) -> int:
-        return len(self._live)
-
-    def slot_pages(self, slot: int) -> list[int]:
-        """The physical pages ``slot`` holds LIVE (mapped and counted
-        against the pool; stale mappings of a retired slot excluded)."""
-        return sorted(
-            int(p) for p in self._table[slot] if p and int(p) in self._live
-        )
-
-    def allocate_slot(
-        self, slot: int, needed_len: int, *, rid: int, wave: int
-    ) -> int:
-        """Map the pages ``slot`` needs to serve a request whose cache
-        never grows past ``needed_len`` rows. Returns the number of
-        pages now live for the slot; raises :class:`PagedOutOfPagesError`
-        (mutating NOTHING) when the free pool cannot cover the need.
-
-        Assignment order is the byte-identity ladder from the module
-        docstring: the slot's own stale pages first, never-mapped free
-        pages next, stolen stale pages of other retired slots last —
-        each group in ascending physical order, so runs are
-        deterministic."""
-        need = self.plan.pages_needed(needed_len)
-        free_set = set(self._free)
-        assigned: dict[int, int] = {}
-        for j in need:
-            p = int(self._table[slot, j])
-            if p and p in free_set:  # (1) stale-self: still mapped here
-                assigned[j] = p
-                free_set.discard(p)
-        remaining = [j for j in need if j not in assigned]
-        avail = sorted(free_set)
-        pool = [p for p in avail if p not in self._owner] + [
-            p for p in avail if p in self._owner
-        ]
-        if len(remaining) > len(pool):
-            raise PagedOutOfPagesError(
-                pages_needed=len(need),
-                pages_free=len(self._free),
-                pages_live=len(self._live),
-                pages_total=self.plan.n_pages_pool,
-            )
-        dirty = False
-        for j, p in zip(remaining, pool):
-            old = self._owner.get(p)
-            if old is not None:  # (3) steal: clear the stale owner's map
-                self._table[old[0], old[1]] = 0
-            self._table[slot, j] = p
-            self._owner[p] = (slot, j)
-            assigned[j] = p
-            dirty = True
-        taken = set(assigned.values())
-        self._free = sorted(set(self._free) - taken)
-        for p in taken:
-            self._live.add(p)
-            self._page_admit[p] = wave
-        self._slot_rid[slot] = rid
-        self.pages_live_peak = max(self.pages_live_peak, len(self._live))
-        if dirty:
-            self._table_dev = jnp.array(self._table)
-        return len(taken)
-
-    def free_slot(self, slot: int, wave: int) -> list[int]:
-        """Return a retired slot's live pages to the free pool and log
-        their occupancy intervals. The table row is NOT cleared (lazy
-        invalidation — see module docstring), so the device table needs
-        no refresh and the retired slot's stale bytes stay readable,
-        exactly like the symmetric baseline's."""
-        released = self.slot_pages(slot)
-        rid = self._slot_rid.get(slot, -1)
-        for p in released:
-            self._live.discard(p)
-            self.page_log.append((p, self._page_admit.pop(p), wave, rid))
-        self._free = sorted(set(self._free) | set(released))
-        return released
-
-    # ------------------------------------------------------- serving
-    def decode(self, params, tokens, pos, active):
-        logits, self.buf = decode_and_wait(
-            self._decode, params, tokens, self.buf, pos, active,
-            self._table_dev,
-        )
-        return logits
-
-    def reset(self, keep):
-        self.buf = self._reset(self.buf, jnp.array(keep), self._table_dev)
-        jax.block_until_ready(self.buf)
-
-    def decode_block(self, params, tokens, pos, active, done, budget, keys,
-                     eos, *, length, sampler) -> BlockOut:
-        """Scan-block decode through the page tables — the contract of
-        :meth:`~repro.runtime.residency.ResidentState.decode_block`.
-        Table mutations happen only at admission and the engine chains
-        blocks only when nothing is queued, so an in-flight block always
-        holds the current table."""
-        jitted = self._block_jits.get(length)
-        if jitted is None:
-            jitted = self._execs.get(block_entry_name("paged", length))
-            if jitted is None:
-                jitted = _LazyJit(
-                    paged_block_impl(
-                        self.model, self._residency, sampler, length
-                    ),
-                    donate_argnums=PAGED_BLOCK_DONATE,
-                )
-            self._block_jits[length] = jitted
-        carry, toks, emitted = jitted(
-            params, self.buf, tokens, pos, active, done, budget, keys, eos,
-            self._table_dev,
-        )
-        self.buf, tokens, pos, done, budget, keys = carry
-        return BlockOut(tokens=tokens, pos=pos, done=done, budget=budget,
-                        keys=keys, wave_tokens=toks, emitted=emitted)
-
-    @property
-    def caches(self) -> Any:
-        """The cache pytree gathered through the live page tables
-        (inspection only; the serving path never materializes this)."""
-        return self._residency.unpack(self.buf, self._table_dev)
-
-    @property
-    def live_bytes(self) -> int:
-        """Pool bytes holding live state — the paged win the report and
-        benches track: ``pages_live * page_size``, vs the symmetric
-        backend's constant ``StatePlan.total_size``."""
-        return len(self._live) * self.plan.page_size
-
-    @property
-    def allocated_bytes(self) -> int:
-        """The physical buffer allocation (null page + whole pool)."""
-        return int(self.buf.nbytes)
+    def retire(self, slot: int, wave: int) -> None:
+        self.pool.free_slot(slot, wave)

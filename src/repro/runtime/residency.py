@@ -1,45 +1,55 @@
 """Plan-backed state residency: the engine's cross-step state in ONE
-device buffer, laid out by the :class:`~repro.core.unified.StatePlan`.
+device buffer, laid out by the :class:`~repro.core.unified.StatePlan`,
+and the one serving backend that holds it.
 
 PR 4 made the cross-step slot/KV layout a first-class planned object —
 but it was accounting only: the engine's cache pytree was still a bag of
 XLA-allocated buffers whose placement the plan merely described. This
 module closes that gap (the MAFAT/FlashMem observation that the §4 win
-comes from *owning* the physical buffers, not modeling them):
+comes from *owning* the physical buffers, not modeling them).
 
-* :class:`StateResidency` binds a cache pytree *structure* to a
-  :class:`~repro.core.unified.StatePlan`: every (slot, leaf) cell is
-  addressed by the plan's :meth:`~repro.core.unified.StatePlan.leaf_view_spec`
-  in one flat buffer of the cache's element dtype (a
+The state is reached through an *addressing*: an object that says how a
+program enters the state, hands it to the model, leaves it and resets
+it. There is one per kind, and everything else is written once:
+
+* :class:`StateResidency` (entry prefix ``resident``, the symmetric
+  state) binds a cache pytree *structure* to a StatePlan: every (slot,
+  leaf) cell is addressed by the plan's
+  :meth:`~repro.core.unified.StatePlan.leaf_view_spec` in one flat
+  buffer of the cache's element dtype (a
   :class:`~repro.runtime.arena.DeviceArena`; no lane-padded byte views
-  on the TPU), and every leaf sits at one offset in every slot region,
-  which is what lets the decode step read and write it in place;
-* :class:`ResidentState` is the serving backend built on it: the decode
-  and slot-reset jits take the flat state buffer as a DONATED argument
-  and return its successor, so XLA reuses the same physical allocation
-  every wave — live device state bytes equal ``StatePlan.total_size``
-  exactly, one allocation for the engine's whole cross-step lifecycle.
-  The decode step updates the buffer in place: the model reads each
-  layer's cache as a view of it (:meth:`StateResidency.slot_buffer`,
-  ``models/state_view.py``) and writes back only the K/V rows and SSM
-  states it changed, and a reset zeroes only the reset slots' regions;
-* :class:`PytreeState` preserves the previous XLA-allocated cache-pytree
-  path behind the same interface (``REPRO_STATE_RESIDENCY=off`` escape
-  hatch), which is also the baseline of the residency differential test:
-  decode outputs through the arena views are byte-identical to it.
+  on the TPU), and every leaf sits at one offset in every slot region.
+  The model takes the buffer as a :class:`SlotBuffer`
+  (``models/state_view.py``): each layer reads its cache as a view of it
+  and writes back only the K/V rows and SSM states it changed, and a
+  reset zeroes only the reset slots' regions;
+* :class:`~repro.runtime.paging.PagedStateResidency` (``paged``)
+  reaches the same logical layout through per-slot page tables over a
+  pool of physical pages, and owns the pool;
+* :class:`PytreeAddressing` (``pytree``) is the XLA-allocated cache
+  pytree, reallocated by value every program
+  (``REPRO_STATE_RESIDENCY=off``). It is the reference of the residency
+  differential tests: decode outputs through the arena views are
+  byte-identical to it.
+
+:class:`ResidentState` serves any of them through one set of programs
+(:func:`decode_impl`, :func:`reset_impl`, :func:`block_impl`). Both
+buffers are a DONATED argument of every program, which returns its
+successor, so XLA reuses the same physical allocation every wave — live
+device state bytes equal ``StatePlan.total_size`` exactly, one
+allocation for the engine's whole cross-step lifecycle.
 
 The initial buffer is packed on the host through the *numpy* arena
 (``Arena.store`` over the same leaf-view spec) and shipped with one
 ``device_put`` — bounds-checked byte placement, no extra jit compile on
 the cold-start path.
 
-**Zero-compile serving (PlanBundle v3).** The decode/reset/scan-block
-functions both backends jit are defined as *module-level factories*
-(:func:`resident_decode_impl` & co.) so three consumers provably lower
-the exact same computation: the serving backends here, the AOT compiler
+**Zero-compile serving (PlanBundle v3).** The program factories are
+module-level so three consumers provably lower the exact same
+computation: the serving backend here, the AOT compiler
 (``runtime/aot.py``, which serializes the compiled executables into the
-bundle), and the static decode lint. Each backend dispatches
-load-or-compile per function: a deserialized AOT executable when the
+bundle), and the static decode lint. The backend dispatches
+load-or-compile per program: a deserialized AOT executable when the
 bundle ships one, else a :class:`_LazyJit` — a ``jax.jit`` wrapper that
 charges the module-global ``COMPILE_CALLS`` counter whenever a call
 actually compiles, so the v3 zero-compile guarantee is counter-asserted
@@ -86,10 +96,9 @@ def count_compile(n: int = 1) -> None:
 
 def decode_and_wait(decode: Callable, *args) -> tuple[Any, Any]:
     """Run one decode-program execution and wait for its new state:
-    ``decode(*args) -> (logits, state)``. Shared by every backend's
-    ``decode()``, under the host spans ``repro.state.decode`` (enqueue
-    plus wait) and ``repro.state.wait``, and counted in
-    ``DECODE_DISPATCHES``."""
+    ``decode(*args) -> (logits, state)``. The backend's ``decode()``,
+    under the host spans ``repro.state.decode`` (enqueue plus wait) and
+    ``repro.state.wait``, and counted in ``DECODE_DISPATCHES``."""
     global DECODE_DISPATCHES
     with counters.span("repro.state.decode"):
         logits, state = decode(*args)
@@ -119,12 +128,15 @@ class _LazyJit:
         return out
 
 
-# Donated argument positions, shared by the serving jits here and the
-# AOT lowering in runtime/aot.py — donation must survive serialization
-# (audited by analysis/decode_lint.lint_executables).
-DECODE_DONATE = (2,)  # (params, tokens, BUF, pos, active)
-RESET_DONATE = (0,)  # (BUF, keep)
-BLOCK_DONATE = (1,)  # (params, BUF, tokens, pos, active, ...)
+# Donated argument positions of the state in every addressing's
+# programs (the paged page table rides LAST and is never donated),
+# shared by the serving jits here and the AOT lowering in
+# runtime/aot.py — donation must survive serialization (audited by
+# analysis/decode_lint.lint_executables). The reference pytree donates
+# nothing (``Addressing.donated``).
+DECODE_DONATE = (2,)  # (params, tokens, BUF, pos, active, *args)
+RESET_DONATE = (0,)  # (BUF, keep, *args)
+BLOCK_DONATE = (1,)  # (params, BUF, tokens, pos, active, ..., *args)
 
 
 def residency_enabled(override: bool | None = None) -> bool:
@@ -168,7 +180,81 @@ def _slot_axis(keypath) -> int:
     return 0
 
 
-class StateResidency:
+class Addressing:
+    """How a state program reaches the engine's cross-step state. The
+    defaults are the cache pytree's, passed by value.
+
+    A program enters the state once (:meth:`enter`, from its state
+    argument and the :meth:`args` after its usual ones), hands the model
+    :meth:`view` of what it carries and takes back :meth:`unview` of
+    what the model returns (a scan block does both every wave), and
+    leaves once (:meth:`leave`: the state argument's successor). A reset
+    is :meth:`reset` between a view and an unview. The serving hooks
+    :meth:`admit` and :meth:`retire` do nothing here."""
+
+    prefix = "pytree"  # AOT entry names: ``{prefix}_decode`` & co.
+    donate = False  # programs donate their state argument
+    pool = None  # the page pool (paged addressing only)
+
+    def donated(self, argnums: tuple) -> tuple:
+        """``argnums`` where programs donate the state, else none."""
+        return argnums if self.donate else ()
+
+    def args(self) -> tuple:
+        """A program's arguments after its usual ones, at serving time."""
+        return ()
+
+    def arg_avals(self) -> tuple:
+        """:meth:`args` shape-level, for lowering."""
+        return tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                     for a in self.args())
+
+    def enter(self, value, *args):
+        return value
+
+    def leave(self, carry, value, *args):
+        return carry
+
+    def view(self, carry):
+        return carry
+
+    def unview(self, state):
+        return state
+
+    def reset(self, model, state, keep):
+        """``state`` with the slots where ``keep`` is False set to the
+        models' all-zero initial state."""
+        return model.reset_slots(state, keep)
+
+    def unpack(self, value, *args) -> Any:
+        """The cache pytree held in ``value`` (for inspection)."""
+        return value
+
+    def live_bytes(self, value) -> int:
+        return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(value))
+
+    def admit(self, slot: int, needed_len: int, *, rid: int,
+              wave: int) -> None:
+        """Called before ``slot`` takes request ``rid``, whose cache
+        never grows past ``needed_len`` rows."""
+
+    def retire(self, slot: int, wave: int) -> None:
+        """Called when ``slot``'s request finishes at ``wave``."""
+
+
+class PytreeAddressing(Addressing):
+    """The reference addressing: the cache pytree XLA allocates, passed
+    to every program by value and returned anew (not donated)."""
+
+    def __init__(self, model, *, n_slots: int, max_len: int):
+        self._init = lambda: model.init_cache(n_slots, max_len)
+        self.aval = jax.eval_shape(self._init)
+
+    def init_buffer(self, caches: Any = None):
+        return self._init() if caches is None else caches
+
+
+class StateResidency(Addressing):
     """Bind a cache-pytree structure to a StatePlan's leaf-view spec.
 
     ``template`` may be concrete arrays or ``jax.eval_shape`` structs —
@@ -176,7 +262,14 @@ class StateResidency:
     the binding completely (path sets match, dtypes match, per-slot byte
     sizes match the plan, the slot axis really has ``n_slots`` extent),
     so a stale or foreign state plan fails here with a clear error
-    instead of corrupting decode state."""
+    instead of corrupting decode state.
+
+    As an :class:`Addressing` it is the symmetric state: the model takes
+    the buffer as a :class:`SlotBuffer`, and a reset zeroes the reset
+    slots' regions."""
+
+    prefix = "resident"
+    donate = True
 
     def __init__(
         self,
@@ -263,6 +356,7 @@ class StateResidency:
             # a leaf stacked over the periods holds one layer per period
             slots.append(LeafSlot(col, per_slot_shape[axis:]))
         self.slots = jax.tree_util.tree_unflatten(self.treedef, slots)
+        self.aval = state_buffer_aval(state_plan)
 
     @property
     def total_size(self) -> int:
@@ -299,10 +393,18 @@ class StateResidency:
         # unsafe to donate through the decode jits
         return jnp.array(host.buf[: self.arena.nbytes].view(self.dtype))
 
-    def slot_buffer(self, buf) -> SlotBuffer:
+    def view(self, buf) -> SlotBuffer:
         """``buf`` as the decode step reads and writes it in place: each
         layer's cache a view of the buffer at the plan's offsets."""
         return SlotBuffer(buf, self.n_slots, self.slot_elems, self.slots)
+
+    def unview(self, state: SlotBuffer):
+        return state.buf
+
+    def reset(self, model, state: SlotBuffer, keep) -> SlotBuffer:
+        del model
+        state.zero_slots(keep)
+        return state
 
     def unpack(self, buf) -> Any:
         """The cache pytree as copies out of ``buf`` — every leaf rebuilt
@@ -320,6 +422,22 @@ class StateResidency:
             ]
             out.append(jnp.stack(per_slot, axis=axis))
         return jax.tree_util.tree_unflatten(self.treedef, out)
+
+
+def state_addressing(
+    state_plan: StatePlan,
+    template: Any,
+    *,
+    n_slots: int,
+    layout: "ArenaLayout | None" = None,
+) -> StateResidency:
+    """The addressing a state plan is served through: paged for a
+    :class:`~repro.core.unified.PagedStatePlan`, else symmetric."""
+    from repro.runtime.paging import PagedStateResidency  # builds on this
+
+    cls = (PagedStateResidency if isinstance(state_plan, PagedStatePlan)
+           else StateResidency)
+    return cls(state_plan, template, n_slots=n_slots, layout=layout)
 
 
 @dataclasses.dataclass
@@ -341,10 +459,10 @@ class BlockOut:
 
 def _block_wave(model, sampler, params, caches, tokens, pos, active, done,
                 budget, keys, eos):
-    """One scan wave, shared by every backend (only the state threading
-    differs: a cache pytree, or a SlotBuffer updated in place): decode
-    at ``active & ~done``, then the sampler's on-device token selection
-    + stop bookkeeping. Inactive/frozen slots keep their token and
+    """One scan wave, shared by every addressing (only the state it
+    carries differs: a cache pytree, or a SlotBuffer updated in place):
+    decode at ``active & ~done``, then the sampler's on-device token
+    selection + stop bookkeeping. Inactive/frozen slots keep their token and
     position, so the cache scatter stays idempotent for them — the same
     invariant the host loop relies on."""
     step_active = active & jnp.logical_not(done)
@@ -360,157 +478,146 @@ def _block_wave(model, sampler, params, caches, tokens, pos, active, done,
 
 # ------------------------------------------------- jitted decode functions
 #
-# Module-level factories for everything the backends jit. The serving
-# backends, the AOT bundle compiler (runtime/aot.py) and the static
+# Module-level factories for everything the backend jits. The serving
+# backend, the AOT bundle compiler (runtime/aot.py) and the static
 # decode lint all lower THESE functions — so "the bundled executable is
 # the executable the engine would have compiled" holds by construction,
 # and the differential tests only need to check numerics, not identity.
+# The addressing's extra arguments (the paged state's page table) come
+# last in every program.
 
 
-def resident_decode_impl(model, residency: StateResidency) -> Callable:
-    """One decode wave over the donated flat state buffer, updated in
-    place (each layer writes back only the rows and states it changed):
-    ``(params, tokens, buf, pos, active) -> (logits, buf')``."""
+def decode_impl(model, addressing: Addressing) -> Callable:
+    """One decode wave: ``(params, tokens, state, pos, active, *args) ->
+    (logits, state')``. On the symmetric state each layer writes back
+    only the rows and states it changed."""
 
-    def decode_step(params, tokens, buf, pos, active):
+    def decode_step(params, tokens, buf, pos, active, *args):
         logits, state = model.decode_step(
-            params, tokens, residency.slot_buffer(buf), pos, active=active
+            params, tokens, addressing.view(addressing.enter(buf, *args)),
+            pos, active=active,
         )
-        return logits, state.buf
+        return logits, addressing.leave(addressing.unview(state), buf, *args)
 
     return decode_step
 
 
-def resident_reset_impl(model, residency: StateResidency) -> Callable:
-    """Slot reset over the donated buffer, ``(buf, keep) -> buf'``: the
-    regions of the slots with ``keep`` False are zeroed (the models'
-    all-zero initial state, as ``model.reset_slots`` gives it) and no
-    other byte is touched."""
-    del model
+def reset_impl(model, addressing: Addressing) -> Callable:
+    """Slot reset, ``(state, keep, *args) -> state'``: the slots with
+    ``keep`` False get the models' all-zero initial state. The symmetric
+    state zeroes just their regions and touches no other byte; the paged
+    state zeroes every page they still map."""
 
-    def reset_slots(buf, keep):
-        state = residency.slot_buffer(buf)
-        state.zero_slots(keep)
-        return state.buf
+    def reset_slots(buf, keep, *args):
+        state = addressing.reset(
+            model, addressing.view(addressing.enter(buf, *args)), keep
+        )
+        return addressing.leave(addressing.unview(state), buf, *args)
 
     return reset_slots
 
 
-def resident_block_impl(
-    model, residency: StateResidency, sampler, length: int
+def block_impl(
+    model, addressing: Addressing, sampler, length: int
 ) -> Callable:
-    """``length`` decode waves in one ``lax.scan`` over the donated
-    buffer, sampling + stop detection on device (see ``_block_wave``)."""
+    """``length`` decode waves in one ``lax.scan``, sampling + stop
+    detection on device (see ``_block_wave``). The state is entered
+    before the scan and left after it: the symmetric buffer itself is
+    the scan carry, while the paged state is gathered through the tables
+    once and scattered back once."""
 
     def decode_block(params, buf, tokens, pos, active, done, budget, keys,
-                     eos):
+                     eos, *args):
         def body(carry, _):
-            buf, tokens, pos, done, budget, keys = carry
+            value, tokens, pos, done, budget, keys = carry
             state, (tokens, pos, done, budget, keys), out = _block_wave(
-                model, sampler, params, residency.slot_buffer(buf), tokens,
+                model, sampler, params, addressing.view(value), tokens,
                 pos, active, done, budget, keys, eos,
             )
-            return (state.buf, tokens, pos, done, budget, keys), out
+            return (addressing.unview(state), tokens, pos, done, budget,
+                    keys), out
 
         carry, (toks, emitted) = jax.lax.scan(
-            body, (buf, tokens, pos, done, budget, keys), None,
-            length=length,
+            body,
+            (addressing.enter(buf, *args), tokens, pos, done, budget, keys),
+            None, length=length,
         )
-        return carry, toks, emitted
-
-    return decode_block
-
-
-def pytree_decode_impl(model) -> Callable:
-    """Decode wave over the XLA-allocated cache pytree:
-    ``(params, tokens, caches, pos, active) -> (logits, caches')``."""
-
-    def decode_step(params, tokens, caches, pos, active):
-        return model.decode_step(params, tokens, caches, pos, active=active)
-
-    return decode_step
-
-
-def pytree_reset_impl(model) -> Callable:
-    def reset_slots(caches, keep):
-        return model.reset_slots(caches, keep)
-
-    return reset_slots
-
-
-def pytree_block_impl(model, sampler, length: int) -> Callable:
-    def decode_block(params, caches, tokens, pos, active, done, budget,
-                     keys, eos):
-        def body(carry, _):
-            caches, tokens, pos, done, budget, keys = carry
-            caches, (tokens, pos, done, budget, keys), out = (
-                _block_wave(model, sampler, params, caches, tokens,
-                            pos, active, done, budget, keys, eos)
-            )
-            return (caches, tokens, pos, done, budget, keys), out
-
-        carry, (toks, emitted) = jax.lax.scan(
-            body, (caches, tokens, pos, done, budget, keys), None,
-            length=length,
-        )
-        return carry, toks, emitted
+        value, tokens, pos, done, budget, keys = carry
+        return ((addressing.leave(value, buf, *args), tokens, pos, done,
+                 budget, keys), toks, emitted)
 
     return decode_block
 
 
 class ResidentState:
-    """Serving backend: cross-step state donate-threaded as ONE buffer.
+    """The serving backend: the engine's cross-step state and the
+    programs that step it, whichever addressing reaches it.
 
-    ``decode``/``reset`` donate the flat state buffer to their jits and
-    keep its successor, so XLA writes the new state into the same
-    physical allocation every wave — the planned layout IS the live
-    layout, and ``live_bytes == StatePlan.total_size`` for the engine's
-    whole lifetime."""
-
-    residency = True
+    On both buffers ``decode``/``reset``/``decode_block`` donate the
+    state to their programs and keep its successor, so XLA writes the
+    new state into the same physical allocation every wave — the
+    planned layout IS the live layout, and on the symmetric state
+    ``live_bytes == StatePlan.total_size`` for the engine's whole
+    lifetime."""
 
     def __init__(
         self,
         model,
-        residency: StateResidency,
+        addressing: Addressing,
         init_caches: Any = None,
         *,
         executables: "dict[str, Any] | None" = None,
     ):
         self.model = model
-        self._residency = residency
-        self.buf = residency.init_buffer(init_caches)
+        self.addressing = addressing
+        self.buf = addressing.init_buffer(init_caches)
         # load-or-compile: a deserialized AOT executable from the bundle
         # when present (zero XLA compiles), else a counted lazy jit of
-        # the SAME impl function the AOT compiler lowered
+        # the SAME program factory the AOT compiler lowered
         self._execs = executables or {}
-        self._decode = self._execs.get("resident_decode") or _LazyJit(
-            resident_decode_impl(model, residency),
-            donate_argnums=DECODE_DONATE,
+        prefix = addressing.prefix
+        self._decode = self._program(
+            f"{prefix}_decode", decode_impl(model, addressing), DECODE_DONATE
         )
-        self._reset = self._execs.get("resident_reset") or _LazyJit(
-            resident_reset_impl(model, residency),
-            donate_argnums=RESET_DONATE,
+        self._reset = self._program(
+            f"{prefix}_reset", reset_impl(model, addressing), RESET_DONATE
         )
         self._block_jits: dict[int, Any] = {}  # scan length -> callable
 
+    def _program(self, entry: str, fn: Callable, donate: tuple):
+        return self._execs.get(entry) or _LazyJit(
+            fn, donate_argnums=self.addressing.donated(donate)
+        )
+
+    @property
+    def residency(self) -> bool:
+        """The state lives in a planned buffer (donated to every
+        program), not in the reference's XLA-allocated pytree."""
+        return self.addressing.donate
+
     def decode(self, params, tokens, pos, active):
         logits, self.buf = decode_and_wait(
-            self._decode, params, tokens, self.buf, pos, active
+            self._decode, params, tokens, self.buf, pos, active,
+            *self.addressing.args(),
         )
         return logits
 
     def reset(self, keep):
-        self.buf = self._reset(self.buf, jnp.array(keep))
+        self.buf = self._reset(
+            self.buf, jnp.array(keep), *self.addressing.args()
+        )
         jax.block_until_ready(self.buf)
 
     def decode_block(self, params, tokens, pos, active, done, budget, keys,
                      eos, *, length, sampler) -> BlockOut:
         """``length`` decode waves in ONE dispatch: ``lax.scan`` over the
-        DONATED state buffer with on-device sampling and stop detection.
-        Returns device handles only — no host sync here; the engine
-        fetches the per-wave outputs when it absorbs the block, and may
-        chain the next block's dispatch off the returned carry first.
+        state with on-device sampling and stop detection. Returns device
+        handles only — no host sync here; the engine fetches the
+        per-wave outputs when it absorbs the block, and may chain the
+        next block's dispatch off the returned carry first. Page tables
+        change only at admission, and the engine chains blocks only when
+        nothing is queued, so an in-flight block always holds the
+        current table.
 
         An AOT executable covers the configured full-size block only
         (tail blocks have engine-chosen shorter lengths and lazy-compile
@@ -518,92 +625,48 @@ class ResidentState:
         a pack entry that matches is safe to run)."""
         jitted = self._block_jits.get(length)
         if jitted is None:
-            jitted = self._execs.get(block_entry_name("resident", length))
-            if jitted is None:
-                jitted = _LazyJit(
-                    resident_block_impl(
-                        self.model, self._residency, sampler, length
-                    ),
-                    donate_argnums=BLOCK_DONATE,
-                )
+            jitted = self._program(
+                block_entry_name(self.addressing.prefix, length),
+                block_impl(self.model, self.addressing, sampler, length),
+                BLOCK_DONATE,
+            )
             self._block_jits[length] = jitted
         carry, toks, emitted = jitted(
-            params, self.buf, tokens, pos, active, done, budget, keys, eos
+            params, self.buf, tokens, pos, active, done, budget, keys, eos,
+            *self.addressing.args(),
         )
         self.buf, tokens, pos, done, budget, keys = carry
         return BlockOut(tokens=tokens, pos=pos, done=done, budget=budget,
                         keys=keys, wave_tokens=toks, emitted=emitted)
 
+    def admit(self, slot: int, needed_len: int, *, rid: int,
+              wave: int) -> None:
+        """Before ``slot`` takes request ``rid``: the paged state maps
+        the pages it needs (raising
+        :class:`~repro.runtime.paging.PagedOutOfPagesError`, with
+        nothing changed, when the pool cannot cover them)."""
+        self.addressing.admit(slot, needed_len, rid=rid, wave=wave)
+
+    def retire(self, slot: int, wave: int) -> None:
+        """When ``slot``'s request finishes: the paged state frees its
+        pages."""
+        self.addressing.retire(slot, wave)
+
+    @property
+    def pages(self):
+        """The page pool (:class:`~repro.runtime.paging.PagePool`), or
+        None where the state is not paged."""
+        return self.addressing.pool
+
     @property
     def caches(self) -> Any:
-        """The cache pytree as live views over the state buffer (for
-        inspection/tracing; decode never materializes this on the host)."""
-        return self._residency.unpack(self.buf)
+        """The cache pytree, copied out of the state buffer (for
+        inspection/tracing; decode never materializes this on the
+        host)."""
+        return self.addressing.unpack(self.buf, *self.addressing.args())
 
     @property
     def live_bytes(self) -> int:
-        return int(self.buf.nbytes)
-
-
-class PytreeState:
-    """The pre-residency backend (``REPRO_STATE_RESIDENCY=off``): caches
-    stay an XLA-allocated pytree, reallocated by value every step. Same
-    interface as :class:`ResidentState`, so the engine is oblivious."""
-
-    residency = False
-
-    def __init__(
-        self,
-        model,
-        init_caches: Any,
-        *,
-        executables: "dict[str, Any] | None" = None,
-    ):
-        self.model = model
-        self.caches = init_caches
-        self._execs = executables or {}
-        self._decode = self._execs.get("pytree_decode") or _LazyJit(
-            pytree_decode_impl(model)
-        )
-        self._reset = self._execs.get("pytree_reset") or _LazyJit(
-            pytree_reset_impl(model)
-        )
-        self._block_jits: dict[int, Any] = {}  # scan length -> callable
-
-    def decode(self, params, tokens, pos, active):
-        logits, self.caches = decode_and_wait(
-            self._decode, params, tokens, self.caches, pos, active
-        )
-        return logits
-
-    def reset(self, keep):
-        self.caches = self._reset(self.caches, jnp.array(keep))
-
-    def decode_block(self, params, tokens, pos, active, done, budget, keys,
-                     eos, *, length, sampler) -> BlockOut:
-        """Scan-block decode over the XLA-allocated cache pytree — the
-        same contract as :meth:`ResidentState.decode_block` (the block
-        path works with residency off; the buffer just isn't donated)."""
-        jitted = self._block_jits.get(length)
-        if jitted is None:
-            jitted = self._execs.get(block_entry_name("pytree", length))
-            if jitted is None:
-                jitted = _LazyJit(
-                    pytree_block_impl(self.model, sampler, length)
-                )
-            self._block_jits[length] = jitted
-        carry, toks, emitted = jitted(
-            params, self.caches, tokens, pos, active, done, budget, keys, eos
-        )
-        self.caches, tokens, pos, done, budget, keys = carry
-        return BlockOut(tokens=tokens, pos=pos, done=done, budget=budget,
-                        keys=keys, wave_tokens=toks, emitted=emitted)
-
-    @property
-    def live_bytes(self) -> int:
-        return int(
-            sum(
-                int(np.prod(x.shape)) * x.dtype.itemsize
-                for x in jax.tree_util.tree_leaves(self.caches)
-            )
-        )
+        """Device bytes holding live state: the whole buffer, the cache
+        pytree's leaves, or the paged state's live pool pages."""
+        return self.addressing.live_bytes(self.buf)

@@ -5,9 +5,9 @@ The tentpole contract, pinned end-to-end:
 * a v3 bundle serves its first token with ZERO XLA compiles — the
   ``COMPILE_CALLS`` counter, same discipline as the zero-trace /
   zero-plan asserts;
-* decode outputs are byte-identical to the lazily-compiled path, on
-  both state backends (resident u8-buffer and plain cache pytree) and
-  on the scan-block path;
+* decode outputs are byte-identical to the lazily-compiled path, for
+  every addressing of the state (symmetric buffer, plain cache pytree,
+  paged buffer) and on the scan-block path;
 * a v2 document degrades to lazy compile (DeprecationWarning, plans
   still served from the bundle — the fingerprint schema rolls
   separately from the bundle format);
@@ -94,30 +94,39 @@ def test_v3_bundle_serves_with_zero_compiles(cfg, params, bundle_dir):
     assert residency.COMPILE_CALLS - c0 == 0
 
 
+@pytest.mark.parametrize("addressing", ["resident", "pytree", "paged"])
 def test_aot_tokens_byte_identical_to_lazy(
-    cfg, params, bundle, bundle_dir, tmp_path
+    cfg, params, bundle, bundle_dir, tmp_path, addressing
 ):
     """The AOT executables ARE the programs the engine would have jitted
-    — same bundle with the pack stripped must emit the same bytes, on
-    both state backends."""
+    — same bundle with the pack stripped must emit the same bytes, for
+    each addressing of the state (symmetric buffer, reference pytree,
+    paged buffer)."""
+    kw = dict(
+        n_slots=N_SLOTS, max_len=MAX_LEN,
+        state_residency=addressing != "pytree",
+    )
+    if addressing == "paged":
+        kw["page_size"] = 1024
+        bundle_dir = tmp_path / "paged"
+        bundle = compile_and_publish(
+            cfg, bundle_dir, n_slots=N_SLOTS, max_len=MAX_LEN,
+            page_size=1024, measure_xla=False,
+        ).bundle
     stripped = tmp_path / "lazy.json"
     save_bundle(dataclasses.replace(bundle, executables=None), stripped)
-    for residency_on in (True, False):
-        kw = dict(
-            n_slots=N_SLOTS, max_len=MAX_LEN, state_residency=residency_on
-        )
-        aot = InferenceEngine(
-            cfg, params, session=PlanSession.from_manifest(bundle_dir), **kw
-        )
-        assert aot.memory_report.aot_executables
-        lazy = InferenceEngine(
-            cfg, params, session=PlanSession.from_bundle(stripped), **kw
-        )
-        assert lazy.memory_report.plan_source == "bundle"
-        assert lazy.memory_report.aot_executables == []
-        assert _serve(aot) == _serve(lazy), (
-            f"AOT tokens diverged from lazy (residency={residency_on})"
-        )
+    aot = InferenceEngine(
+        cfg, params, session=PlanSession.from_manifest(bundle_dir), **kw
+    )
+    assert f"{addressing}_decode" in aot.memory_report.aot_executables
+    lazy = InferenceEngine(
+        cfg, params, session=PlanSession.from_bundle(stripped), **kw
+    )
+    assert lazy.memory_report.plan_source == "bundle"
+    assert lazy.memory_report.aot_executables == []
+    assert _serve(aot) == _serve(lazy), (
+        f"AOT tokens diverged from lazy ({addressing})"
+    )
 
 
 def test_aot_block_path_zero_compile_and_identical(cfg, params, tmp_path):

@@ -70,8 +70,8 @@ def test_greedy_block_decode_identical_across_block_sizes():
 
 
 def test_block_decode_works_with_residency_off():
-    """The scan path is backend-agnostic: PytreeState (residency off)
-    serves the same tokens as the donated-buffer backend."""
+    """The scan path serves every addressing: the reference pytree
+    (residency off) serves the same tokens as the donated buffer."""
     cfg = get_reduced("qwen3-0.6b")
     params = _params(cfg)
     prompts = _prompts(cfg)

@@ -162,26 +162,19 @@ def test_engine_accepts_pre_searched_graph():
     assert len(done) == 1 and len(done[0].tokens) == 3
 
 
-def test_legacy_plan_source_kwargs_warn():
-    """The pre-unified kwargs keep working behind a DeprecationWarning
-    (the shim maps them onto a PlanSession)."""
+def test_session_from_spec_pins_the_strategy():
+    """A PlanSession over a PlanSpec plans the activations with the
+    strategy it names."""
     from repro.core.unified import PlanSession, PlanSpec
 
     cfg = get_reduced("qwen3-0.6b")
     model = Model.for_config(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    with pytest.deprecated_call(match="session=PlanSession"):
-        legacy = InferenceEngine(cfg, params, n_slots=2, max_len=32,
-                                 plan_strategy="greedy_by_size")
-    new = InferenceEngine(
+    engine = InferenceEngine(
         cfg, params, n_slots=2, max_len=32,
         session=PlanSession.from_spec(PlanSpec(strategy="greedy_by_size")),
     )
-    assert (
-        legacy.memory_report.activation_plan.strategy
-        == new.memory_report.activation_plan.strategy
-        == "greedy_by_size"
-    )
+    assert engine.memory_report.activation_plan.strategy == "greedy_by_size"
 
 
 def test_engine_memory_report():

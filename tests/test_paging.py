@@ -158,7 +158,7 @@ def test_paged_decode_byte_identical_to_symmetric(arch):
     prompts = _prompts(cfg)
     sym, sym_tokens = _run(cfg, params, prompts)
     paged, paged_tokens = _run(cfg, params, prompts, page_size=1024)
-    assert paged.state.paged and not getattr(sym.state, "paged", False)
+    assert paged.state.pages is not None and sym.state.pages is None
     assert paged_tokens == sym_tokens
     assert [tuple(x) for x in paged.slot_log] == \
         [tuple(x) for x in sym.slot_log]
@@ -181,7 +181,7 @@ def test_paged_decode_non_divisor_page_size():
     for page in (1000, 4096):
         paged, got = _run(cfg, params, prompts, page_size=page)
         assert got == ref, f"page_size={page} diverged"
-        assert paged.state.pages_live == 0, "drained engine frees all pages"
+        assert paged.state.pages.live == 0, "drained engine frees all pages"
 
 
 def test_paged_seeded_sampling_matches_symmetric():
@@ -233,8 +233,8 @@ def test_slot_reuse_frees_and_recycles_pages():
         by_page.setdefault(page, set()).add(rid)
     assert any(len(rids) > 1 for rids in by_page.values()), \
         "no physical page was ever recycled across requests"
-    assert engine.state.pages_live == 0
-    assert engine.state.pages_live_peak > 0
+    assert engine.state.pages.live == 0
+    assert engine.state.pages.peak > 0
 
 
 def test_from_page_log_rejects_double_assignment_and_null_page():
@@ -261,7 +261,7 @@ def test_live_paged_bytes_beat_symmetric_plan_at_low_fill():
     engine, _ = _run(cfg, params, _prompts(cfg, sizes=(4,)), max_new=4,
                      n_slots=4, page_size=512)
     sp = engine.memory_report.state_plan
-    peak = engine.state.pages_live_peak * sp.page_size
+    peak = engine.state.pages.peak * sp.page_size
     assert peak > 0
     assert peak * 3 <= sp.total_size, (
         f"peak live {peak} B not 3x under symmetric {sp.total_size} B"
@@ -274,7 +274,7 @@ def test_memory_report_honest_under_paging():
     engine = InferenceEngine(cfg, params, n_slots=2, max_len=64,
                              page_size=1024)
     rep0 = engine.memory_report
-    assert rep0.state_pages_total == engine.state.pages_total
+    assert rep0.state_pages_total == engine.state.pages.total
     assert rep0.state_pages_live == 0
     assert rep0.state_page_size == 1024
     assert rep0.cache_bytes_per_slot == 0, "no live pages, no cache bytes"
@@ -284,7 +284,7 @@ def test_memory_report_honest_under_paging():
         engine.submit(p, max_new_tokens=6)
     engine.step()
     rep = engine.memory_report
-    assert rep.state_pages_live == engine.state.pages_live > 0
+    assert rep.state_pages_live == engine.state.pages.live > 0
     assert rep.state_live_bytes == rep.state_pages_live * 1024
     # live-page bytes per ACTIVE slot, not the symmetric per-slot stride
     assert rep.cache_bytes_per_slot == rep.state_live_bytes // 2
@@ -314,7 +314,7 @@ def test_out_of_pages_refuses_without_corruption():
     tight, got = _run(cfg, params, prompts, page_size=1024,
                       page_pool=2 * need - 1)
     assert got == ref, "pool pressure changed decoded tokens"
-    assert tight.state.pages_live_peak <= 2 * need - 1
+    assert tight.state.pages.peak <= 2 * need - 1
     slots_busy = [
         {s for s, a, f, _ in tight.slot_log if a <= w <= f}
         for w in range(tight._wave)
@@ -350,7 +350,7 @@ def test_unfinished_requests_under_pool_pressure():
     with pytest.warns(RuntimeWarning, match="exhausted"):
         engine.run_until_done(max_waves=4)
     assert len(engine.unfinished_requests()) >= 1
-    assert engine.state.pages_live <= per_slot
+    assert engine.state.pages.live <= per_slot
 
 
 # ------------------------------------------------------- artifact serving
@@ -391,7 +391,7 @@ def test_paged_bundle_serves_with_zero_work(tmp_path):
     assert engine.memory_report.plan_source == "bundle", (
         engine.memory_report.bundle_warning
     )
-    assert engine.state.paged
+    assert engine.state.pages is not None
     assert cap.delta("trace_calls") == 0
     assert cap.delta("plan_calls") == 0
     assert cap.delta("state_plan_calls") == 0
@@ -436,6 +436,6 @@ def test_residency_off_falls_back_to_symmetric_with_warning():
                         page_size=1024, state_residency=False)
     engine = InferenceEngine(cfg, params, n_slots=2, max_len=64,
                              state_residency=False)
-    assert not getattr(engine.state, "paged", False)
+    assert engine.state.pages is None
     engine.submit(_prompts(cfg, sizes=(4,))[0], max_new_tokens=4)
     assert len(engine.run_until_done()) == 1

@@ -32,7 +32,7 @@ from repro.models.api import Model
 from repro.runtime.arena import Arena, ArenaLayout, DeviceArena
 from repro.runtime.engine import InferenceEngine
 from repro.runtime.residency import (
-    PytreeState,
+    PytreeAddressing,
     ResidentState,
     StateResidency,
     residency_enabled,
@@ -273,9 +273,10 @@ DECODE_CASES = [pytest.param(a, "all", id=a) for a in ARCHS] + [
 
 
 def _assert_blocks_identical(arch, cfg, params, sp, res, resident, baseline):
-    """Scan blocks of waves on both backends, from one slot idle and the
-    others stopping at different waves: the same tokens, and after each
-    block the baseline's cache laid out by the plan, byte for byte."""
+    """Scan blocks of waves on the resident and reference states, from
+    one slot idle and the others stopping at different waves: the same
+    tokens, and after each block the baseline's cache laid out by the
+    plan, byte for byte."""
     n = sp.n_slots
     sampler = TokenSampler(SamplingParams(), max_len=sp.max_len)
     rng = np.random.default_rng(1)
@@ -315,7 +316,9 @@ def test_decode_byte_identical_to_xla_allocated_baseline(arch, case):
     cfg, model, params, caches, sp = _setup(arch, n_slots=n)
     res = StateResidency(sp, caches, n_slots=n)
     resident = ResidentState(model, res, caches)
-    baseline = PytreeState(model, caches)
+    baseline = ResidentState(
+        model, PytreeAddressing(model, n_slots=n, max_len=sp.max_len), caches
+    )
     assert resident.live_bytes == sp.total_size
     if case == "block":
         _assert_blocks_identical(arch, cfg, params, sp, res, resident,
@@ -473,7 +476,7 @@ def test_env_escape_hatch_disables_residency(monkeypatch):
     engine = InferenceEngine(cfg, params, n_slots=2, max_len=32)
     rep = engine.memory_report
     assert not rep.state_residency
-    assert isinstance(engine.state, PytreeState)
+    assert isinstance(engine.state.addressing, PytreeAddressing)
     assert rep.state_live_bytes == sum(
         int(np.prod(x.shape)) * x.dtype.itemsize
         for x in jax.tree_util.tree_leaves(engine.caches)

@@ -203,9 +203,13 @@ def test_engine_from_bundle_no_trace_no_plan_no_state_layout(
     assert rep.state_plan is not None
     assert rep.state_plan == engine.plan_bundle.state_plan
     assert engine.unified_plan.total_size == engine.plan_bundle.total_size
-    # the arena is materialized straight from the stored offsets, and the
-    # state layout from the stored slot/KV plan
-    assert engine.activation_arena.nbytes == max(rep.activation_plan.total_size, 1)
+    # the activation layout comes straight from the stored offsets, and
+    # the state layout from the stored slot/KV plan
+    engine.activation_layout.validate()
+    assert engine.activation_layout.total_size == rep.activation_plan.total_size
+    assert engine.activation_layout.offsets == dict(
+        engine.plan_bundle.plan.offsets
+    )
     engine.state_layout.validate()
     assert engine.state_layout.total_size == rep.state_plan.total_size
 
@@ -351,23 +355,6 @@ def test_v1_bundle_on_v2_engine_falls_back(
     assert rep.state_plan is not None
     engine.submit(np.arange(3, dtype=np.int32), max_new_tokens=2)
     assert len(engine.run_until_done()) == 1
-
-
-def test_legacy_plan_bundle_kwarg_warns_and_serves(cfg, params, bundle_dir):
-    """The deprecated kwargs keep working behind a DeprecationWarning and
-    exact-bucket semantics."""
-    with pytest.deprecated_call(match="session=PlanSession"):
-        engine = InferenceEngine(
-            cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN,
-            plan_bundle=bundle_dir,
-        )
-    assert engine.memory_report.plan_source == "bundle"
-    with pytest.raises(ValueError, match="not both"):
-        InferenceEngine(
-            cfg, params, n_slots=N_SLOTS, max_len=MAX_LEN,
-            session=PlanSession.from_manifest(bundle_dir),
-            plan_bundle=bundle_dir,
-        )
 
 
 def test_verify_bundle_checks_graph_fingerprint(cfg, params, bundle_dir, tmp_path):

@@ -86,7 +86,7 @@ def test_ssd_chunk_lowers_to_mosaic(on_chip):
 def _decode_program(backend: str, on_chip, arch: str = "qwen3-0.6b",
                     slots: int = SLOTS):
     """(jitted decode step, avals) of full-width ``arch`` on one chip —
-    the same impl factories and donation the serving backends jit."""
+    the same program factories and donation the serving backend jits."""
     from repro.configs.base import get_config
     from repro.core.unified import (
         detect_state_axes,
@@ -109,25 +109,23 @@ def _decode_program(backend: str, on_chip, arch: str = "qwen3-0.6b",
         jax.ShapeDtypeStruct((slots,), jnp.bool_),
     ))
     if backend == "pytree":
-        fn = jax.jit(residency.pytree_decode_impl(model))
-        return fn, (*head, on_chip(caches), *tail)
-    if backend == "resident":
+        addressing = residency.PytreeAddressing(
+            model, n_slots=slots, max_len=MAX_LEN
+        )
+    elif backend == "resident":
         plan = plan_state(records, n_slots=slots, max_len=MAX_LEN)
-        res = residency.StateResidency(plan, caches, n_slots=slots)
-        fn = jax.jit(residency.resident_decode_impl(model, res),
-                     donate_argnums=residency.DECODE_DONATE)
-        return fn, (*head, on_chip(residency.state_buffer_aval(plan)), *tail)
-    plan = plan_paged_state(
-        records, n_slots=SLOTS, max_len=MAX_LEN, page_size=1 << 20,
-        axes=detect_state_axes(model.init_cache, n_slots=SLOTS,
-                               max_len=MAX_LEN),
-    )
-    res = paging.PagedStateResidency(plan, caches, n_slots=SLOTS)
-    pages = jax.ShapeDtypeStruct((SLOTS, plan.pages_per_slot), jnp.int32)
-    fn = jax.jit(paging.paged_decode_impl(model, res),
-                 donate_argnums=paging.PAGED_DECODE_DONATE)
-    return fn, (*head, on_chip(residency.state_buffer_aval(plan)), *tail,
-                on_chip(pages))
+        addressing = residency.StateResidency(plan, caches, n_slots=slots)
+    else:
+        plan = plan_paged_state(
+            records, n_slots=SLOTS, max_len=MAX_LEN, page_size=1 << 20,
+            axes=detect_state_axes(model.init_cache, n_slots=SLOTS,
+                                   max_len=MAX_LEN),
+        )
+        addressing = paging.PagedStateResidency(plan, caches, n_slots=SLOTS)
+    fn = jax.jit(residency.decode_impl(model, addressing),
+                 donate_argnums=addressing.donated(residency.DECODE_DONATE))
+    return fn, (*head, on_chip(addressing.aval), *tail,
+                *on_chip(addressing.arg_avals()))
 
 
 @pytest.mark.parametrize("backend,arch,slots", [
