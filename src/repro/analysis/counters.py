@@ -4,7 +4,7 @@ The repo instruments its hot paths with module-global counters
 (``tracer.TRACE_CALLS``, ``planner.PLAN_CALLS``,
 ``unified.STATE_PLAN_CALLS``, ``engine.HOST_SYNCS``,
 ``residency.COMPILE_CALLS``, ``residency.DECODE_DISPATCHES``,
-``engine.SAMPLE_FETCHES``) that
+``engine.SAMPLE_FETCHES``, ``attention.LANE_ROW_ATTENTION``) that
 tests, CI and benches snapshot/delta to pin caching and sync behaviour.
 Before this module each call site hand-rolled the same
 ``t0, p0, s0 = tracer.TRACE_CALLS, planner.PLAN_CALLS, ...`` boilerplate;
@@ -49,6 +49,9 @@ REGISTRY: dict[str, tuple[str, str]] = {
     # one per host-loop wave: the device-to-host fetch its sampling makes
     # (greedy picks or the wave's logits), on either sampling path
     "sample_fetches": ("repro.runtime.engine", "SAMPLE_FETCHES"),
+    # one per attention layer traced through the lane-row kernel, which
+    # reads K and V where they lie in the state buffer (trace time)
+    "lane_row_attention": ("repro.models.attention", "LANE_ROW_ATTENTION"),
 }
 
 

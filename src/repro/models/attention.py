@@ -15,6 +15,7 @@ Design points (TPU-shaped):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable
 
 import jax
@@ -195,6 +196,58 @@ def attn_prefill(
     return out, (k, v)
 
 
+# Attention layers traced through the lane-row kernel
+# (counters.REGISTRY "lane_row_attention"), one per layer per trace.
+LANE_ROW_ATTENTION = 0
+
+
+def _attend_in_place(q, cache, pos_b, *, n_kv: int, head_dim: int,
+                     scale: float):
+    """The decode attention core read where K and V lie in the state
+    buffer (``kernels/lane_row_attention.py``): ``(B, 1, H * D)``, or
+    None where the einsum over a ``(B, T, KV, D)`` view stays. The
+    kernel reads views of a buffer of 128-lane rows whose heads straddle
+    rows (``head_dim`` neither a multiple nor a divisor of 128, as
+    zamba2's 224): that view is not the buffer's own tiling, and XLA
+    would copy and relayout the whole leaf every step. Heads that fill
+    whole rows or divide one XLA reads in place, and they keep the
+    einsum. On a platform without Mosaic (the CPU) the same kernel runs
+    in the Pallas interpreter."""
+    global LANE_ROW_ATTENTION
+    from repro.kernels import lane_row_attention as lra
+
+    k, v = cache
+    if (head_dim % lra.LANES == 0 or lra.LANES % head_dim == 0
+            or not (isinstance(k, state_view.LeafView)
+                    and isinstance(v, state_view.LeafView))):
+        return None
+    state = k.state
+    if state.lane != lra.LANES or state.buf.dtype != q.dtype:
+        return None
+    B, T = k.shape[:2]
+    span = n_kv * head_dim // lra.LANES
+    groups = q.shape[2] // n_kv
+    block_t = lra.block_tokens(
+        T, span, [s.col // lra.LANES for s in (k.slot, v.slot)],
+        q.dtype.itemsize, groups * n_kv,
+    )
+    if block_t is None:
+        return None
+    rows, k_first = state.rows(k.slot, k.layer)
+    _, v_first = state.rows(v.slot, v.layer)
+    first = jnp.stack([jnp.asarray(k_first, jnp.int32),
+                       jnp.asarray(v_first, jnp.int32)]) // (block_t * span)
+    call = functools.partial(lra.lane_row_attention, t_len=T,
+                             block_t=block_t, scale=scale)
+    o = jax.lax.platform_dependent(
+        rows, first, pos_b, q.reshape(B, n_kv, groups, head_dim),
+        tpu=functools.partial(call, interpret=False),
+        default=functools.partial(call, interpret=True),
+    )
+    LANE_ROW_ATTENTION += 1
+    return o.reshape(B, 1, n_kv * groups * head_dim)
+
+
 def attn_decode(
     p: dict,
     x: jax.Array,  # (B, 1, D)
@@ -231,14 +284,19 @@ def attn_decode(
     # one-hot masked rewrite of the full cache)
     new_cache = (state_view.write_rows(cache[0], slot_b, k[:, 0]),
                  state_view.write_rows(cache[1], slot_b, v[:, 0]))
+    if scale is None:
+        scale = 1.0 / np.sqrt(head_dim)
+    if window is None and constrain is None:
+        out = _attend_in_place(q, new_cache, pos_b, n_kv=n_kv,
+                               head_dim=head_dim, scale=float(scale))
+        if out is not None:
+            return out @ p["wo"], new_cache
     k_cache, v_cache = map(state_view.read, new_cache)
     if constrain is not None:
         k_cache = constrain(k_cache, "kv_heads")
         v_cache = constrain(v_cache, "kv_heads")
         if not isinstance(new_cache[0], state_view.LeafView):
             new_cache = (k_cache, v_cache)
-    if scale is None:
-        scale = 1.0 / np.sqrt(head_dim)
     s = _gqa_scores(q, k_cache) * scale  # (B,H,1,T)
     idx = jnp.arange(T)[None, None, None, :]
     pb = pos_b[:, None, None, None]

@@ -98,6 +98,13 @@ class SlotBuffer:
         """Row of the view where ``layer`` of the leaf starts."""
         return slot.col // self.lane + layer * (slot.size // self.lane)
 
+    def rows(self, slot: LeafSlot, layer):
+        """The buffer as its ``(n_slots, slot_elems / lane, lane)`` view
+        and the row where ``layer`` of the leaf starts: what a kernel
+        that reads the leaf where it lies is given. No copy is made and
+        nothing is scoped: the reader's own ops are not state moved."""
+        return self._rows(self.buf), self._first_row(slot, layer)
+
     def read(self, slot: LeafSlot, layer):
         """``layer`` of the leaf across all slots, ``(n_slots, *shape)``."""
 

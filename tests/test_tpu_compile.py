@@ -11,9 +11,12 @@ chip's 16 GiB. These cases compile, at real widths:
 * the decode step of full-width qwen3-0.6b at 8 slots x 4096 tokens on
   each state backend — the cache pytree, the resident flat buffer and
   the paged pool (1 MiB pages) — each of which must fit one chip, and
-  full-width mamba2-2.7b's at 16 slots on the resident buffer. The
-  resident step updates the donated buffer in place: it aliases it, and
-  its temporaries stay under a quarter of the state.
+  full-width mamba2-2.7b's at 16 slots and zamba2-7b's first 12 layers
+  at 8 slots on the resident buffer. The resident step updates the
+  donated buffer in place: it aliases it, and its temporaries stay under
+  a quarter of the state. Attention whose heads straddle 128-lane rows
+  reads K and V there through the lane-row kernel (zamba2's shared
+  blocks: no relayouted leaf, temporaries under one leaf).
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -84,9 +87,12 @@ def test_ssd_chunk_lowers_to_mosaic(on_chip):
 
 
 def _decode_program(backend: str, on_chip, arch: str = "qwen3-0.6b",
-                    slots: int = SLOTS):
-    """(jitted decode step, avals) of full-width ``arch`` on one chip —
-    the same program factories and donation the serving backend jits."""
+                    slots: int = SLOTS, overrides: dict | None = None):
+    """(jitted decode step, avals) of full-width ``arch`` (with the
+    config ``overrides``) on one chip — the same program factories and
+    donation the serving backend jits."""
+    import dataclasses
+
     from repro.configs.base import get_config
     from repro.core.unified import (
         detect_state_axes,
@@ -97,7 +103,8 @@ def _decode_program(backend: str, on_chip, arch: str = "qwen3-0.6b",
     from repro.models.api import Model
     from repro.runtime import paging, residency
 
-    model = Model.for_config(get_config(arch))
+    model = Model.for_config(
+        dataclasses.replace(get_config(arch), **(overrides or {})))
     caches = jax.eval_shape(lambda: model.init_cache(slots, MAX_LEN))
     records = state_records_from_pytree(caches, n_slots=slots)
     head = on_chip((
@@ -128,15 +135,31 @@ def _decode_program(backend: str, on_chip, arch: str = "qwen3-0.6b",
                 *on_chip(addressing.arg_avals()))
 
 
-@pytest.mark.parametrize("backend,arch,slots", [
-    pytest.param("pytree", "qwen3-0.6b", SLOTS, id="pytree"),
-    pytest.param("resident", "qwen3-0.6b", SLOTS, id="resident"),
-    pytest.param("paged", "qwen3-0.6b", SLOTS, id="paged"),
-    pytest.param("resident", "mamba2-2.7b", 16, id="resident-mamba2-2.7b"),
+# Attention layers each decode trace reads through the lane-row kernel:
+# one per traced layer whose heads straddle 128-lane rows and whose K
+# and V are views of the resident buffer (zamba2's two shared-block
+# invocations, heads of 224); none on the pytree or paged state, nor for
+# heads that divide a row (qwen3-0.6b's 64).
+@pytest.mark.parametrize("backend,arch,slots,overrides,lane_row", [
+    pytest.param("pytree", "qwen3-0.6b", SLOTS, None, 0, id="pytree"),
+    pytest.param("resident", "qwen3-0.6b", SLOTS, None, 0, id="resident"),
+    pytest.param("paged", "qwen3-0.6b", SLOTS, None, 0, id="paged"),
+    pytest.param("resident", "mamba2-2.7b", 16, None, 0,
+                 id="resident-mamba2-2.7b"),
+    # the benchmark's stage: layers 0-11, both shared blocks
+    pytest.param("resident", "zamba2-7b", SLOTS, {"depth": 12}, 2,
+                 id="resident-zamba2-7b"),
 ])
-def test_full_width_decode_step_fits_one_v5e(backend, arch, slots, on_chip):
-    fn, args = _decode_program(backend, on_chip, arch, slots)
-    ma = fn.lower(*args).compile().memory_analysis()
+def test_full_width_decode_step_fits_one_v5e(backend, arch, slots, overrides,
+                                             lane_row, on_chip):
+    from repro.analysis import counters
+
+    fn, args = _decode_program(backend, on_chip, arch, slots, overrides)
+    with counters.capture("lane_row_attention") as cap:
+        lowered = fn.lower(*args)
+    assert cap.delta("lane_row_attention") == lane_row
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
     live = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     print(f"{backend} decode on v5e: temp {ma.temp_size_in_bytes} B, "
@@ -154,3 +177,14 @@ def test_full_width_decode_step_fits_one_v5e(backend, arch, slots, on_chip):
         assert ma.temp_size_in_bytes < state_bytes / 4, (
             f"{arch}: temp {ma.temp_size_in_bytes} B against a "
             f"{state_bytes} B state")
+    text = compiled.as_text()
+    if lane_row:
+        assert "tpu_custom_call" in text
+    if arch == "zamba2-7b":
+        # the shared blocks read K and V where they lie: no leaf relayouted
+        # to (slots, T, heads, 224), and less temporary space than one
+        # K or V leaf (8 x 4096 x 7168 bf16)
+        assert "[8,4096,32,224]" not in text
+        leaf_bytes = SLOTS * MAX_LEN * 7168 * 2
+        assert ma.temp_size_in_bytes < leaf_bytes, (
+            f"temp {ma.temp_size_in_bytes} B against a {leaf_bytes} B leaf")
